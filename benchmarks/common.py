@@ -21,17 +21,11 @@ import jax
 def timeit(fn: Callable, *, warmup: int = 1, iters: int = 3) -> float:
     """Median wall seconds over ``iters`` runs (after warmup)."""
     for _ in range(warmup):
-        r = fn()
-        jax.block_until_ready(r) if hasattr(r, "block_until_ready") \
-            or isinstance(r, (list, tuple, dict)) else None
+        jax.block_until_ready(fn())
     times = []
     for _ in range(iters):
         t0 = time.perf_counter()
-        r = fn()
-        try:
-            jax.block_until_ready(r)
-        except Exception:
-            pass
+        jax.block_until_ready(fn())
         times.append(time.perf_counter() - t0)
     times.sort()
     return times[len(times) // 2]

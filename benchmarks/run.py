@@ -14,6 +14,8 @@ import argparse
 import sys
 import traceback
 
+from repro import compile_cache
+
 # named suites: shorthand for section subsets (--suite groupby ==
 # --only serving_groupby)
 SUITES = {
@@ -37,6 +39,7 @@ def main() -> None:
     ap.add_argument("--suite", default=None, choices=sorted(SUITES),
                     help="named section subset")
     args = ap.parse_args()
+    compile_cache.enable()
 
     from benchmarks import lm_benchmarks, q_benchmarks, serving_benchmarks
 

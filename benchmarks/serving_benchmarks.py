@@ -63,6 +63,7 @@ import os
 import time
 
 from benchmarks.common import row
+from repro import compile_cache
 from repro.core import QueryService
 from repro.core.serving import CostBasedBucketing
 from repro.core.workload import (DEFAULT_TENANTS, make_groupby_workload,
@@ -1147,6 +1148,7 @@ def main() -> None:
     ap.add_argument("--repeats", type=int, default=None)
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    compile_cache.enable()
     variants = args.variants or (4 if args.smoke else 64)
     repeats = args.repeats or (1 if args.smoke else 3)
     out = args.out or ("BENCH_serving_smoke.json" if args.smoke

@@ -6,6 +6,7 @@ Shows the full paper pipeline: XML -> columnar shred -> parse ->
 normalized plan -> rewritten plan (watch the §4 rules fire) ->
 fused SPMD execution -> results.
 """
+from repro import compile_cache
 from repro.core import ExecConfig, Executor, compile_query, translate
 from repro.core.algebra import pretty
 from repro.core.rewrite import optimize
@@ -20,6 +21,7 @@ return $r
 
 
 def main() -> None:
+    compile_cache.enable()
     print("=== 1. build + shred the weather collection (4 partitions)")
     db = build_database(WeatherSpec(num_stations=10,
                                     years=(2000, 2001),

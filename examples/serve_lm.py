@@ -9,6 +9,7 @@ sharding, launch/mesh.py cache_specs).
 """
 import argparse
 
+from repro import compile_cache
 from repro.launch.serve import serve_batch
 
 
@@ -18,6 +19,7 @@ def main() -> None:
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--gen", type=int, default=24)
     args = ap.parse_args()
+    compile_cache.enable()
     out = serve_batch(args.arch, num_requests=args.requests,
                       prompt_len=48, gen_len=args.gen)
     print(f"generated {out['generated'].shape[0]} x "

@@ -13,6 +13,7 @@ import argparse
 import shutil
 import tempfile
 
+from repro import compile_cache
 from repro.checkpoint import latest_step
 from repro.launch.train import train
 
@@ -23,6 +24,7 @@ def main() -> None:
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--full", action="store_true")
     args = ap.parse_args()
+    compile_cache.enable()
 
     ckpt = tempfile.mkdtemp(prefix="vxjax_ckpt_")
     try:

@@ -1,15 +1,15 @@
-import os
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=8")
-
-"""Cluster-mode XQuery: shard_map over an 8-device data axis.
+"""Cluster-mode XQuery: shard_map over a data axis of every device.
 
     PYTHONPATH=src python examples/xquery_cluster.py
 
+On the CPU, simulate eight devices with
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``; on a TPU host
+the mesh spans its chips. The database gets one partition per device.
+
 The same compiled plan as quickstart, but executed as a true SPMD
-program over 8 (simulated) devices with lax collectives at the
-exchange points: all_gather for the hybrid-hash join build side and
-psum for the two-step aggregation — the Hyracks connector analogues
+program over the mesh with lax collectives at the exchange points:
+all_gather for the hybrid-hash join build side and psum for the
+two-step aggregation — the Hyracks connector analogues
 (DESIGN.md §2). Also runs the grace-repartition strategy for the
 large-large join (Q8), mirroring the paper's hybrid-vs-grace
 discussion.
@@ -18,19 +18,21 @@ import time
 
 import jax
 
-from repro import compat
+from repro import compile_cache
 from repro.core import ExecConfig, Executor, QueryService, compile_query
 from repro.core.queries import ALL
 from repro.data.weather import WeatherSpec, build_database
 
 
 def main() -> None:
-    print(f"devices: {len(jax.devices())}")
+    compile_cache.enable()
+    n = len(jax.devices())
+    print(f"devices: {n}")
     db = build_database(WeatherSpec(num_stations=16,
                                     years=(1976, 2000, 2001),
                                     days_per_year=4),
-                        num_partitions=8)
-    mesh = compat.make_mesh((8,), ("data",))
+                        num_partitions=n)
+    mesh = jax.make_mesh((n,), ("data",), (jax.sharding.AxisType.Auto,))
 
     for name, strat in [("Q5", "broadcast"), ("Q7", "broadcast"),
                         ("Q8", "repartition")]:

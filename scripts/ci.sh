@@ -39,8 +39,10 @@
 #                                 the segment-engine parity suite under
 #                                 REPRO_KERNEL_INTERPRET=1 (the Pallas
 #                                 interpreter executes the exact TPU
-#                                 kernel bodies on CPU) plus the vmapped
-#                                 kernel-vs-jnp policy sweep smoke
+#                                 kernel bodies on CPU), the kernels'
+#                                 compiles for a described TPU v5e, and
+#                                 the vmapped kernel-vs-jnp policy sweep
+#                                 smoke
 #   scripts/ci.sh --persist       also run the persistent-plan-cache
 #                                 stage standalone (disk cache round
 #                                 trip, restart parity, corruption and
@@ -110,7 +112,8 @@ if [ "$PROPERTIES" = "1" ]; then
 fi
 if [ "$KERNELS" = "1" ]; then
     REPRO_KERNEL_INTERPRET=1 python -m pytest -x -q \
-        tests/test_seg_kernels.py tests/test_kernels.py
+        tests/test_seg_kernels.py tests/test_kernels.py \
+        tests/test_tpu_compile.py
     python -m benchmarks.serving_benchmarks --smoke --suite kernels
 fi
 if [ "$OBS" = "1" ]; then

@@ -23,6 +23,10 @@ Node = tuple[str, int, int]      # (collection, partition, node index)
 @dataclasses.dataclass
 class SaxonLike:
     db: xdm.Database
+    # (collection, partition) -> (node rows sorted stably by parent,
+    # their parents): each node's children are one contiguous run, in
+    # document order, found by binary search instead of a table scan
+    _kids: dict = dataclasses.field(default_factory=dict, repr=False)
 
     # -- node helpers --------------------------------------------------------
 
@@ -35,12 +39,14 @@ class SaxonLike:
         f = self.db.names.lookup(name)
         if f < 0:
             return []
-        out = []
-        js = np.nonzero(t.parent == idx)[0]
-        for j in js:
-            if t.name[j] == f:
-                out.append((coll, p, int(j)))
-        return out
+        kids = self._kids.get((coll, p))
+        if kids is None:
+            order = np.argsort(t.parent, kind="stable")
+            kids = self._kids[(coll, p)] = (order, t.parent[order])
+        order, parents = kids
+        js = order[np.searchsorted(parents, idx, "left"):
+                   np.searchsorted(parents, idx, "right")]
+        return [(coll, p, int(j)) for j in js if t.name[j] == f]
 
     def string_value(self, node: Node) -> str:
         return node_fingerprint(self.db, node[0], node[1], node[2])

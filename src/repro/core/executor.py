@@ -26,9 +26,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro import compat
 from repro.core import algebra as A
 from repro.core import xdm
+from repro.core.errors import InvalidArgumentError
 from repro.core.physical import (Col, ExprEval, Tile, _gather,
                                  device_tables, path_match_mask,
                                  rows_from_mask, topk_rows)
@@ -211,7 +211,7 @@ class Comm:
     def size(self) -> int:
         if not self.axis:
             return 1
-        return compat.axis_size(self.axis)
+        return lax.axis_size(self.axis)
 
 
 # ---------------------------------------------------------------------------
@@ -353,6 +353,8 @@ class Executor:
         parts = {len(c.partitions) for c in db.collections.values()}
         assert len(parts) == 1, "collections must agree on partitioning"
         self.num_partitions = parts.pop()
+        # the mesh the tables were last placed on (spmd mode)
+        self._placed_on: Optional[tuple] = None
         # observability for the service layer's cache assertions
         self.compile_count = 0      # Executor.compile invocations
         self.trace_count = 0        # actual local-fn traces (retraces)
@@ -372,6 +374,36 @@ class Executor:
             else:
                 axes[k] = jax.tree.map(lambda _: 0, v)
         return axes
+
+    def _table_specs(self, axis: str) -> dict:
+        """PartitionSpec tree: collections split over ``axis`` (one
+        partition per device), the derived arrays replicated."""
+        from jax.sharding import PartitionSpec as P
+        return {k: jax.tree.map(lambda _: P() if k == "__derived__"
+                                else P(axis), v)
+                for k, v in self.tables.items()}
+
+    def place_on_mesh(self, mesh, axis: str = "data") -> None:
+        """Put the tables on ``mesh`` once — partition p on device p,
+        ``__derived__`` replicated — so spmd calls find their inputs
+        where shard_map wants them instead of resharding from one
+        device on every call. Each device holds exactly one partition,
+        so a mesh axis whose size differs from the partition count
+        raises ``InvalidArgumentError`` instead of dropping partitions."""
+        size = mesh.shape[axis]
+        if size != self.num_partitions:
+            raise InvalidArgumentError(
+                f"mesh axis {axis!r} has {size} devices but the "
+                f"database has {self.num_partitions} partitions; "
+                f"build it with num_partitions={size}")
+        if self._placed_on == (mesh, axis):
+            return
+        from jax.sharding import NamedSharding, PartitionSpec
+        shardings = jax.tree.map(
+            lambda spec: NamedSharding(mesh, spec), self._table_specs(axis),
+            is_leaf=lambda x: isinstance(x, PartitionSpec))
+        self.tables = jax.device_put(self.tables, shardings)
+        self._placed_on = (mesh, axis)
 
     # -- plan compilation ----------------------------------------------------
 
@@ -461,12 +493,9 @@ class Executor:
                                 batch=batch, profile_meta=prof_meta)
         if mode == "spmd":
             from jax.sharding import PartitionSpec as P
-            from jax.experimental.shard_map import shard_map
 
-            table_specs = {k: (jax.tree.map(lambda _: P(), v)
-                               if k == "__derived__" else
-                               jax.tree.map(lambda _: P(axis), v))
-                           for k, v in self.tables.items()}
+            self.place_on_mesh(mesh, axis)
+            table_specs = self._table_specs(axis)
 
             def local_spmd(tables, params=()):
                 # shard_map keeps the (now size-1) partition axis;
@@ -496,8 +525,8 @@ class Executor:
             else:
                 in_specs = (table_specs,)
             out_spec = P(None, axis) if batch is not None else P(axis)
-            sm = shard_map(local_spmd, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_spec, check_rep=False)
+            sm = jax.shard_map(local_spmd, mesh=mesh, in_specs=in_specs,
+                               out_specs=out_spec, check_vma=False)
             out_fn = jit(sm)
             if aot and not donate:
                 out_fn = self._aot_compile(out_fn, param_specs, batch)

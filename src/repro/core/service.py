@@ -308,6 +308,8 @@ class QueryService:
         # path free of it. Off only for ablation/benchmark isolation.
         self.verify = verify
         self.executor = Executor(db, self.base_config)
+        if mode == "spmd":
+            self.executor.place_on_mesh(mesh)
         self.stats = ServiceStats()
         # observability: spans go to the attached tracer (default: the
         # shared no-op NULL_TRACER — the pre-instrumentation warm
@@ -337,9 +339,9 @@ class QueryService:
         self._profile_cache: OrderedDict[tuple, CompiledPlan] = \
             OrderedDict()
         # disk-backed persistent compiled-plan cache (core/persist.py).
-        # When enabled, compilations go ahead-of-time (executor
-        # aot=True) so the executable is a serializable value; loads
-        # are fingerprint-checked (jax/jaxlib/backend/device, kernel
+        # Serving compilations go ahead-of-time (executor aot=True), so
+        # the executable is a serializable value; loads are
+        # fingerprint-checked (jax/jaxlib/backend/device, kernel
         # env, mode, partitions, db digest) so a foreign environment's
         # entry is invalidated and recompiled, never served
         self._persist = None
@@ -526,16 +528,17 @@ class QueryService:
     def _compile(self, plan: A.Op, cfg: ExecConfig, sig: str,
                  param_specs: tuple, batch: Optional[int],
                  profile: bool) -> CompiledPlan:
-        """One real trace+compile (the only site). AOT (lower+compile
-        to a concrete executable) when persistence is on, so the
-        result is serializable; profile variants always go the lazy
-        route — they are never persisted."""
+        """One real trace+compile (the only site). Serving variants
+        compile ahead of time (lower+compile to a concrete executable)
+        — so ``warmup`` leaves nothing for the first request to
+        compile, and the executable can be persisted; profile variants
+        go the lazy route — they are never persisted."""
         t0 = time.perf_counter()  # lint: allow(DET001) — compile-time metric, cold path only
         with self.tracer.span("compile", cat="service") as span:
             cp = self.executor.compile(
                 plan, mode=self.mode, mesh=self.mesh, config=cfg,
                 param_specs=param_specs, batch=batch, profile=profile,
-                aot=self._persist is not None and not profile)
+                aot=not profile)
             span.set(sig=sig_digest(sig), batch=batch,
                      profile=profile)
         # counted after the compile succeeds, so `stats.compiles` stays
@@ -615,10 +618,17 @@ class QueryService:
     def cache_size(self) -> int:
         return len(self._cache)
 
+    def cached_plans(self) -> list[CompiledPlan]:
+        """Every cached serving compilation, least recently used first:
+        the last entry is the plan the latest request ran. Its ``fn``
+        is the ahead-of-time executable (``as_text``,
+        ``memory_analysis``)."""
+        return list(self._cache.values())
+
     def cached_configs(self) -> list[ExecConfig]:
         """ExecConfig of every cached compilation (observability for
         benchmarks/tests without leaking the cache-key layout)."""
-        return [cp.config for cp in self._cache.values()]
+        return [cp.config for cp in self.cached_plans()]
 
     def binding_stats(self) -> dict[tuple, int]:
         """Exact (signature, binding) hit counts — the stats-only

@@ -60,6 +60,9 @@ class WeatherSpec:
             return "NEW YORK"
         return STATES[i % len(STATES)]
 
+    def station_name(self, i: int) -> str:
+        return f"STATION {i} {self.station_state(i)} AIRPORT"
+
     def station_is_us(self, i: int) -> bool:
         return i < 2 or (i % self.foreign_every) != self.foreign_every - 1
 
@@ -206,8 +209,7 @@ def _station_tables(spec: WeatherSpec, db: xdm.Database, parts: int
         for i in range(p, spec.num_stations, parts):
             st = sh.element("station", root)
             sh.element("id", st, spec.station_id(i))
-            sh.element("displayName", st,
-                       f"STATION {i} {spec.station_state(i)} AIRPORT")
+            sh.element("displayName", st, spec.station_name(i))
             sh.element("latitude", st, f"{25 + (i % 40)}.5")
             sh.element("longitude", st, f"-{70 + (i % 50)}.25")
             lab = sh.element("locationLabels", st)

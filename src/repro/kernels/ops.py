@@ -8,8 +8,9 @@ backend interpret switches off.
 The *segment engine* entry points (``segmented_aggregate``,
 ``segment_topk``) are three-way instead: on TPU they run the Pallas
 kernel; on CPU they run the kernel's jnp twin from ``kernels.ref``
-(bit-identical by construction, and fast — the twin is scatter-free,
-so XLA CPU never serializes it into while loops); with
+(bitwise equal on counts, extrema and integer-valued sums, and fast —
+the twin is scatter-free, so XLA CPU never serializes it into while
+loops); with
 ``REPRO_KERNEL_INTERPRET=1`` they force the Pallas interpreter, which
 is how CI validates the TPU kernel code on CPU
 (``scripts/ci.sh --kernels``). ``REPRO_FORCE_JNP=1`` forces the jnp
@@ -108,14 +109,6 @@ def hash_join_probe(build_keys, build_valid, probe_keys, probe_valid,
     return pos, matched, jnp.zeros((), jnp.bool_)
 
 
-@partial(jax.jit, static_argnames=("num_segments", "block_n"))
-def segmented_sum_count(values, segments, valid, num_segments,
-                        block_n=512):
-    return _seg.segmented_sum_count(
-        values, segments, valid, num_segments, block_n=block_n,
-        interpret=_interpret())
-
-
 def segmented_aggregate(values, ok, segments, valid, num_segments):
     """Fused segment aggregation (executor group-by entry point).
     values/ok: [N, C] (C >= 0 value columns); segments/valid: [N].
@@ -131,15 +124,15 @@ def segmented_aggregate(values, ok, segments, valid, num_segments):
                                            num_segments)
         return c, s[:, :0], mn[:, :0], mx[:, :0]
     impl = _seg_impl()
-    bn = _block_divisor(n)
     if impl == "jnp":
         if num_segments > SEG_DENSE_NSEG_MAX:
             return _ref.segmented_aggregate_scatter(
                 values, ok, segments, valid, num_segments)
         return _ref.segmented_aggregate(values, ok, segments, valid,
-                                        num_segments, block_n=bn)
+                                        num_segments,
+                                        block_n=_block_divisor(n))
     return _seg.segmented_aggregate(values, ok, segments, valid,
-                                    num_segments, block_n=bn,
+                                    num_segments,
                                     interpret=(impl == "interpret"))
 
 

@@ -79,12 +79,13 @@ def segmented_sum_count(values, segments, valid, num_segments):
 
 def segmented_aggregate(values, ok, segments, valid, num_segments, *,
                         block_n=512):
-    """jnp twin of seg_aggregate.segmented_aggregate — the same
-    blocked one-hot accumulation the kernel grid performs, so the two
-    agree bitwise; the dot_general sums also accumulate in row order,
-    matching the legacy scatter-add path bit-for-bit on CPU. This is
-    the CPU fast path: no scatter, so XLA never lowers it to a serial
-    while loop.
+    """jnp twin of seg_aggregate.segmented_aggregate: the same
+    blocked one-hot accumulation the kernel grid performs. This is the
+    CPU fast path: no scatter, so XLA never lowers it to a serial
+    while loop. The one-hot sums run at ``Precision.HIGHEST`` — at
+    default precision a TPU matmul rounds its f32 operands to bf16,
+    which holds integers exactly only up to 256 — so counts, min/max
+    and integer-valued sums below 2**24 equal the kernel's bitwise.
 
     values/ok: [N, C]; segments/valid: [N] ->
     (counts [S], sums [S, C], mins [S, C], maxs [S, C])."""
@@ -106,7 +107,8 @@ def segmented_aggregate(values, ok, segments, valid, num_segments, *,
         counts = counts + jnp.sum(ohf, axis=0)
         okm = ok[sl] & vld[:, None]
         sums = sums + jax.lax.dot_general(
-            ohf, jnp.where(okm, v, 0.0), (((0,), (0,)), ((), ())))
+            ohf, jnp.where(okm, v, 0.0), (((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST)
         m = oh[:, :, None] & okm[:, None, :]          # (bn, S, C)
         vb = v[:, None, :]
         mins = jnp.minimum(mins, jnp.min(

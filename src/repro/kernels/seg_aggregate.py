@@ -1,15 +1,28 @@
 """Segmented aggregation kernel (Pallas TPU) — rule 4.2.2's local step.
 
 Two-step aggregation reduces each partition locally before the global
-exchange. When the aggregate is keyed (per-station, per-day — Q6-style
-workloads and the LM data pipeline's per-bucket stats), the local step
-is a segmented reduction. TPU-native trick: scatter-add has no good
-MXU form, but ``one_hot(seg) @ values`` is a (bn, S) × (bn,) matmul —
-so the kernel builds the one-hot tile on the fly and accumulates the
-segment sums/counts in a VMEM-resident (S,) output across grid steps.
+exchange. When the aggregate is keyed (per-station, per-day — the
+group-by queries), the local step is a segmented reduction. TPU-native
+form: scatter has no good vector form, but a one-hot tile does — the
+kernel builds the transposed one-hot ``(S, bn)`` (segment ids down the
+sublanes, rows along the lanes) on the fly from a lane-dense row of
+segment ids, and folds count/sum/min/max for every value column into
+VMEM-resident ``(S, ·)`` outputs across the row blocks.
 
-VMEM per step: one-hot (bn, S) f32 ≈ 2 MB at bn=512, S=1024; choose
-bn·S ≤ ~4M to stay inside budget.
+Exactness: every reduction runs on the VPU in f32 — masked selects and
+lane reductions, no MXU pass — so no operand is ever rounded to bf16.
+Counts and integer-valued sums below 2**24 (the sensor archive's
+integer tenths) are exact in any order; min/max are exact always.
+
+Layout (Mosaic's rules): segment ids are a (1, N) int32 row, values
+and their masks (C, N) — lane-dense in N, with validity as 0/1 int32
+rather than bool — so a vmap over partitions only adds a leading
+dimension. N is padded to the row block (a multiple of 128 lanes) and
+S to a multiple of 8 sublanes; the padding is masked out and sliced
+off.
+
+VMEM per step: a few (S, bn) f32 temporaries — 1 MB each at
+S = 512, bn = 512.
 """
 from __future__ import annotations
 
@@ -19,31 +32,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _kernel(vals_ref, seg_ref, valid_ref, sum_ref, cnt_ref, *,
-            num_segments: int, bn: int):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        sum_ref[...] = jnp.zeros_like(sum_ref)
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
-
-    v = vals_ref[...].astype(jnp.float32)
-    seg = seg_ref[...]
-    ok = valid_ref[...] & (seg >= 0) & (seg < num_segments)
-    v = jnp.where(ok, v, 0.0)
-    seg_ids = jax.lax.broadcasted_iota(jnp.int32, (bn, num_segments), 1)
-    onehot = (seg_ids == seg[:, None]) & ok[:, None]   # (bn, S)
-    oh = onehot.astype(jnp.float32)
-    # (S,) += (S, bn) @ (bn,)
-    sum_ref[...] += jax.lax.dot_general(oh, v, (((0,), (0,)), ((), ())))
-    cnt_ref[...] += jnp.sum(oh, axis=0)
+LANE = 128
+SUBLANE = 8
 
 
-def _agg_kernel(vals_ref, ok_ref, seg_ref, valid_ref,
-                cnt_ref, sum_ref, min_ref, max_ref, *,
-                num_segments: int, bn: int, nc: int):
+def _agg_kernel(seg_ref, vals_ref, ok_ref, cnt_ref, sum_ref, min_ref,
+                max_ref, *, s: int, bn: int, nc: int):
     """Fused multi-column segment aggregation: one pass over the row
     blocks accumulates count/sum/min/max for every value column at
     once — no per-aggregate rescan, no full-width intermediate."""
@@ -56,25 +50,27 @@ def _agg_kernel(vals_ref, ok_ref, seg_ref, valid_ref,
         min_ref[...] = jnp.full_like(min_ref, jnp.inf)
         max_ref[...] = jnp.full_like(max_ref, -jnp.inf)
 
-    seg = seg_ref[...]
-    vld = valid_ref[...] & (seg >= 0) & (seg < num_segments)
-    seg_ids = jax.lax.broadcasted_iota(jnp.int32, (bn, num_segments), 1)
-    oh = (seg_ids == seg[:, None]) & vld[:, None]          # (bn, S)
-    ohf = oh.astype(jnp.float32)
-    cnt_ref[...] += jnp.sum(ohf, axis=0)
-    v = vals_ref[...].astype(jnp.float32)                  # (bn, C)
-    okm = ok_ref[...] & vld[:, None]                       # (bn, C)
-    # sums via one-hot matmul — the same row-order accumulation as the
-    # executor's scatter-add reference, so float bits agree
-    sum_ref[...] += jax.lax.dot_general(
-        ohf, jnp.where(okm, v, 0.0), (((0,), (0,)), ((), ())))
-    for c in range(nc):   # static unroll; min/max are order-exact
-        m = oh & okm[:, c][:, None]                        # (bn, S)
-        vc = v[:, c][:, None]
-        min_ref[:, c] = jnp.minimum(
-            min_ref[:, c], jnp.min(jnp.where(m, vc, jnp.inf), axis=0))
-        max_ref[:, c] = jnp.maximum(
-            max_ref[:, c], jnp.max(jnp.where(m, vc, -jnp.inf), axis=0))
+    seg_ids = jax.lax.broadcasted_iota(jnp.int32, (s, bn), 0)
+    oh = seg_ids == seg_ref[...]                       # (S, bn)
+    cnt_ref[...] += jnp.sum(oh.astype(jnp.float32), axis=1, keepdims=True)
+    v = vals_ref[...]                                  # (C, bn)
+    ok = ok_ref[...] != 0
+    for c in range(nc):   # static unroll over the value columns
+        m = oh & ok[c:c + 1]                           # (S, bn)
+        vc = v[c:c + 1]
+        col = slice(c, c + 1)
+        sum_ref[:, col] += jnp.sum(jnp.where(m, vc, 0.0), axis=1,
+                                   keepdims=True)
+        min_ref[:, col] = jnp.minimum(
+            min_ref[:, col],
+            jnp.min(jnp.where(m, vc, jnp.inf), axis=1, keepdims=True))
+        max_ref[:, col] = jnp.maximum(
+            max_ref[:, col],
+            jnp.max(jnp.where(m, vc, -jnp.inf), axis=1, keepdims=True))
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
 def segmented_aggregate(values: jax.Array, ok: jax.Array,
@@ -83,7 +79,7 @@ def segmented_aggregate(values: jax.Array, ok: jax.Array,
                         interpret: bool = False
                         ) -> tuple[jax.Array, jax.Array, jax.Array,
                                    jax.Array]:
-    """values/ok: [N, C]; segments/valid: [N]. Returns
+    """values/ok: [N, C] (C >= 1); segments/valid: [N]. Returns
     (counts [S], sums [S, C], mins [S, C], maxs [S, C]).
 
     ``valid`` masks rows out of the segment space entirely (counts
@@ -91,61 +87,33 @@ def segmented_aggregate(values: jax.Array, ok: jax.Array,
     exclusion) out of sum/min/max while the row still counts. Empty
     (segment, column) slots read +/-inf in mins/maxs — callers mask
     on counts. jnp twin: kernels.ref.segmented_aggregate."""
+    assert block_n % LANE == 0, block_n
     n, nc = values.shape
-    bn = min(block_n, n)
-    assert n % bn == 0, (n, bn)
-    kernel = functools.partial(_agg_kernel, num_segments=num_segments,
-                               bn=bn, nc=nc)
     s = num_segments
-    return pl.pallas_call(
+    bn = min(block_n, _round_up(n, LANE))
+    npad = _round_up(n, bn)
+    spad = _round_up(s, SUBLANE)
+    vld = valid & (segments >= 0) & (segments < s)
+    # out-of-space and invalid rows get segment -1: no one-hot bit
+    seg = jnp.where(vld, segments.astype(jnp.int32), -1)
+    seg = jnp.pad(seg, (0, npad - n), constant_values=-1).reshape(1, npad)
+    pad_cols = ((0, 0), (0, npad - n))
+    vals_t = jnp.pad(values.astype(jnp.float32).T, pad_cols)
+    ok_t = jnp.pad((ok & vld[:, None]).astype(jnp.int32).T, pad_cols)
+    kernel = functools.partial(_agg_kernel, s=spad, bn=bn, nc=nc)
+    out_spec = pl.BlockSpec((spad, nc), lambda i: (0, 0))
+    cnt, sums, mins, maxs = pl.pallas_call(
         kernel,
-        grid=(n // bn,),
+        grid=(npad // bn,),
         in_specs=[
-            pl.BlockSpec((bn, nc), lambda i: (i, 0)),
-            pl.BlockSpec((bn, nc), lambda i: (i, 0)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
+            pl.BlockSpec((nc, bn), lambda i: (0, i)),
+            pl.BlockSpec((nc, bn), lambda i: (0, i)),
         ],
-        out_specs=[
-            pl.BlockSpec((s,), lambda i: (0,)),
-            pl.BlockSpec((s, nc), lambda i: (0, 0)),
-            pl.BlockSpec((s, nc), lambda i: (0, 0)),
-            pl.BlockSpec((s, nc), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((s,), jnp.float32),
-            jax.ShapeDtypeStruct((s, nc), jnp.float32),
-            jax.ShapeDtypeStruct((s, nc), jnp.float32),
-            jax.ShapeDtypeStruct((s, nc), jnp.float32),
-        ],
+        out_specs=[pl.BlockSpec((spad, 1), lambda i: (0, 0)),
+                   out_spec, out_spec, out_spec],
+        out_shape=[jax.ShapeDtypeStruct((spad, 1), jnp.float32)]
+        + [jax.ShapeDtypeStruct((spad, nc), jnp.float32)] * 3,
         interpret=interpret,
-    )(values, ok, segments.astype(jnp.int32), valid)
-
-
-def segmented_sum_count(values: jax.Array, segments: jax.Array,
-                        valid: jax.Array, num_segments: int, *,
-                        block_n: int = 512, interpret: bool = False
-                        ) -> tuple[jax.Array, jax.Array]:
-    """values/segments/valid: [N]; returns (sums [S], counts [S])."""
-    n = values.shape[0]
-    bn = min(block_n, n)
-    assert n % bn == 0, (n, bn)
-    kernel = functools.partial(_kernel, num_segments=num_segments, bn=bn)
-    return pl.pallas_call(
-        kernel,
-        grid=(n // bn,),
-        in_specs=[
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((num_segments,), lambda i: (0,)),
-            pl.BlockSpec((num_segments,), lambda i: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((num_segments,), jnp.float32),
-            jax.ShapeDtypeStruct((num_segments,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(values, segments.astype(jnp.int32), valid)
+    )(seg, vals_t, ok_t)
+    return cnt[:s, 0], sums[:s], mins[:s], maxs[:s]

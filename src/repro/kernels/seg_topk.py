@@ -18,6 +18,9 @@ the jnp reference's stable lexsort order bit-for-bit. Keys must be
 NaN-free (the executor's aggregate columns are — NaN values are
 masked out of every aggregate before ordering).
 
+The loop carries its selected-row mask as int32: Mosaic cannot carry
+a bool vector through ``scf.for``.
+
 VMEM: (nkeys + 2) · (1, N) rows ≈ a few KB at N ≤ 4096.
 """
 from __future__ import annotations
@@ -29,6 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 BIG_I32 = 2**31 - 1
+LANE = 128
 
 
 def _sentinel(dtype):
@@ -38,15 +42,15 @@ def _sentinel(dtype):
     return jnp.asarray(BIG_I32, dtype)
 
 
-def _topk_kernel(*refs, cap: int, nkeys: int, n: int):
+def _topk_kernel(*refs, cap: int, nkeys: int, n: int, cpad: int):
     key_refs = refs[:nkeys]
     out_ref = refs[nkeys]
     iota = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
-    iota_cap = jax.lax.broadcasted_iota(jnp.int32, (1, cap), 1)
+    iota_cap = jax.lax.broadcasted_iota(jnp.int32, (1, cpad), 1)
 
     def body(m, carry):
         selected, out = carry
-        m0 = ~selected
+        m0 = selected == 0
         # lexicographic argmin over the unselected rows: narrow the
         # tie set one key row at a time, then break on row index —
         # the stable-sort order
@@ -57,11 +61,11 @@ def _topk_kernel(*refs, cap: int, nkeys: int, n: int):
             m0 = m0 & (kv == cur)
         idx_m = jnp.min(jnp.where(m0, iota, n))
         out = jnp.where(iota_cap == m, idx_m, out)
-        selected = selected | (iota == idx_m)
+        selected = jnp.where(iota == idx_m, 1, selected)
         return selected, out
 
-    sel0 = jnp.zeros((1, n), jnp.bool_)
-    out0 = jnp.zeros((1, cap), jnp.int32)
+    sel0 = jnp.zeros((1, n), jnp.int32)
+    out0 = jnp.zeros((1, cpad), jnp.int32)
     _, out = jax.lax.fori_loop(0, cap, body, (sel0, out0))
     out_ref[...] = out
 
@@ -75,7 +79,8 @@ def segment_topk(keys: tuple[jax.Array, ...], cap: int, *,
     on row index). jnp twin: kernels.ref.segment_topk."""
     n = keys[0].shape[0]
     assert 0 < cap <= n, (cap, n)
-    npad = -(-n // 128) * 128
+    npad = -(-n // LANE) * LANE
+    cpad = -(-cap // LANE) * LANE
     padded = []
     for i, k in enumerate(keys):
         # pad rows carry flag 2 — strictly greater than any real row's
@@ -86,13 +91,13 @@ def segment_topk(keys: tuple[jax.Array, ...], cap: int, *,
         padded.append(jnp.pad(k, (0, npad - n),
                               constant_values=fill).reshape(1, npad))
     kernel = functools.partial(_topk_kernel, cap=cap, nkeys=len(keys),
-                               n=npad)
+                               n=npad, cpad=cpad)
     out = pl.pallas_call(
         kernel,
         in_specs=[pl.BlockSpec((1, npad), lambda: (0, 0))
                   for _ in padded],
-        out_specs=pl.BlockSpec((1, cap), lambda: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, cap), jnp.int32),
+        out_specs=pl.BlockSpec((1, cpad), lambda: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((1, cpad), jnp.int32),
         interpret=interpret,
     )(*padded)
-    return out[0]
+    return out[0, :cap]

@@ -26,23 +26,23 @@ from typing import Any, Optional
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.models.model import ModelConfig
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(num_devices: Optional[int] = None) -> Mesh:
     """Small mesh over the actual local devices (tests/examples)."""
     n = num_devices or len(jax.devices())
-    return compat.make_mesh((n, 1), ("data", "model"))
+    return jax.make_mesh((n, 1), ("data", "model"),
+                         (AxisType.Auto, AxisType.Auto))
 
 
 def _axis_size(mesh: Mesh, name) -> int:

@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
-
 Params = Any
 
 
@@ -55,7 +53,7 @@ def compressed_mean(grads: Params, ef: ErrorFeedback, axis: str
                     ) -> tuple[Params, ErrorFeedback]:
     """Int8+EF mean over ``axis``. Must run inside shard_map/vmap with
     that axis name in scope."""
-    n = compat.axis_size(axis)
+    n = lax.axis_size(axis)
 
     def one(g, r):
         g = g.astype(jnp.float32) + r

@@ -41,12 +41,14 @@ def check_result(rs, oracle, name, rel=1e-3, grouped_rel=1e-5):
         assert canon(rs.rows()) == oracle[name]
 
 
+WEATHER_SPEC = WeatherSpec(num_stations=8,
+                           years=(1976, 1999, 2000, 2001, 2003, 2004),
+                           days_per_year=3)
+
+
 @pytest.fixture(scope="session")
 def weather_db():
-    spec = WeatherSpec(num_stations=8,
-                       years=(1976, 1999, 2000, 2001, 2003, 2004),
-                       days_per_year=3)
-    return build_database(spec, num_partitions=4)
+    return build_database(WEATHER_SPEC, num_partitions=4)
 
 
 @pytest.fixture(scope="session")

@@ -79,8 +79,7 @@ def test_restore_onto_different_sharding(tmp_path):
     """Elastic restore: checkpoint written unsharded restores onto an
     explicit (1-device here) NamedSharding target."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro import compat
-    mesh = compat.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), (jax.sharding.AxisType.Auto,))
     t = {"w": jnp.arange(16.0).reshape(4, 4)}
     save(str(tmp_path), 2, t)
     sh = {"w": NamedSharding(mesh, P("data", None))}

@@ -25,7 +25,6 @@ def run_py(code: str, devices: int = 8) -> str:
 def test_queries_spmd_8dev():
     print(run_py('''
 import jax
-from repro import compat
 from repro.core import Executor, ExecConfig, compile_query
 from repro.core.baselines import SaxonLike
 from repro.core.queries import ALL, SCALAR
@@ -33,7 +32,7 @@ from repro.data.weather import WeatherSpec, build_database
 
 db = build_database(WeatherSpec(num_stations=8, years=(1976, 2000, 2001),
                                 days_per_year=3), num_partitions=8)
-mesh = compat.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), (jax.sharding.AxisType.Auto,))
 sx = SaxonLike(db)
 for name in ("Q1", "Q4", "Q5", "Q8"):
     for strat in ("broadcast", "repartition"):
@@ -58,14 +57,14 @@ def test_scheduled_batch_spmd_8dev():
     bindings on 8 partitions, bit-identical to per-request spmd
     execution (including through the async submit/drain runtime)."""
     print(run_py('''
-from repro import compat
+import jax
 from repro.core import QueryService
 from repro.core.workload import variant_grid
 from repro.data.weather import WeatherSpec, build_database
 
 db = build_database(WeatherSpec(num_stations=8, years=(1976, 2000, 2001),
                                 days_per_year=3), num_partitions=8)
-mesh = compat.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), (jax.sharding.AxisType.Auto,))
 stations = ["GHCND:USW00012836", "GHCND:USW00014771"]
 years = (1976, 2000, 2001)
 texts = variant_grid("Q1", stations, years, 4) + variant_grid("Q3", stations, years, 3)
@@ -92,14 +91,13 @@ print("SPMD-BATCH-8 OK")
 def test_sharded_train_step_8dev():
     print(run_py('''
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
 from repro.configs import get_smoke_config
 from repro.launch import mesh as mesh_lib
 from repro.models import model as model_lib, steps as steps_lib
 from repro.optim import adamw_init
 
 cfg = get_smoke_config("llama3-8b")
-mesh = compat.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"), (jax.sharding.AxisType.Auto,) * 2)
 params = model_lib.init_params(cfg, jax.random.key(0))
 opt = adamw_init(params)
 pspecs = mesh_lib.named(mesh, mesh_lib.param_specs(cfg, mesh))
@@ -141,9 +139,8 @@ from repro.optim import adamw_init
 from repro.runtime import ElasticState, remesh_plan
 from repro.runtime.elastic import build_mesh_from_plan
 
-from repro import compat
 cfg = get_smoke_config("qwen3-1.7b")
-mesh8 = compat.make_mesh((4, 2), ("data", "model"))
+mesh8 = jax.make_mesh((4, 2), ("data", "model"), (jax.sharding.AxisType.Auto,) * 2)
 params = model_lib.init_params(cfg, jax.random.key(0))
 opt = adamw_init(params)
 p8 = mesh_lib.named(mesh8, mesh_lib.param_specs(cfg, mesh8))

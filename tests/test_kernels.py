@@ -9,7 +9,7 @@ from repro.kernels import ops, ref
 from repro.kernels.decode_attention import decode_attention_bhgd
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.hash_join import block_join_probe
-from repro.kernels.seg_aggregate import segmented_sum_count
+from repro.kernels.seg_aggregate import segmented_aggregate
 
 RNG = np.random.default_rng(7)
 
@@ -108,7 +108,7 @@ def test_block_join_sweep(nb, np_, nkeys):
     bv = jnp.asarray(RNG.random(nb) > 0.15)
     pv = jnp.asarray(RNG.random(np_) > 0.15)
     pos, matched = block_join_probe(tuple(bk), bv, tuple(pk), pv,
-                                    block_p=64, block_b=64,
+                                    block_p=128, block_b=128,
                                     interpret=True)
     wpos, wm = ref.block_join_probe(tuple(bk), bv, tuple(pk), pv)
     np.testing.assert_array_equal(np.asarray(pos), np.asarray(wpos))
@@ -133,14 +133,17 @@ def test_join_kernel_agrees_with_executor_probe():
 @pytest.mark.parametrize("n,s,bn", [(512, 32, 128), (2048, 128, 512),
                                     (1024, 7, 256)])
 def test_segmented_sum_count(n, s, bn):
+    """The kernel's one-column sum/count equals the executor's legacy
+    twin, ``ref.segmented_sum_count``."""
     vals = jnp.asarray(RNG.normal(size=n), jnp.float32)
     segs = jnp.asarray(RNG.integers(-1, s + 2, n), jnp.int32)
     valid = jnp.asarray(RNG.random(n) > 0.25)
-    got_s, got_c = segmented_sum_count(vals, segs, valid, s,
-                                       block_n=bn, interpret=True)
+    got_c, got_s, _, _ = segmented_aggregate(
+        vals[:, None], jnp.ones((n, 1), bool), segs, valid, s,
+        block_n=bn, interpret=True)
     want_s, want_c = ref.segmented_sum_count(vals, segs, valid, s)
-    np.testing.assert_allclose(np.asarray(got_s), np.asarray(want_s),
-                               atol=1e-4)
+    np.testing.assert_allclose(np.asarray(got_s[:, 0]),
+                               np.asarray(want_s), atol=1e-4)
     np.testing.assert_array_equal(np.asarray(got_c),
                                   np.asarray(want_c))
 

@@ -3,9 +3,9 @@ no devices needed for spec computation)."""
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.launch import mesh as mesh_lib
 from repro.models import model as model_lib
@@ -13,9 +13,8 @@ from repro.models import model as model_lib
 
 def abstract_pod(multi=False):
     if multi:
-        return compat.make_abstract_mesh((2, 16, 16),
-                                         ("pod", "data", "model"))
-    return compat.make_abstract_mesh((16, 16), ("data", "model"))
+        return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 @pytest.mark.parametrize("arch", ARCHS)

@@ -3,6 +3,7 @@ aggregate columns + LIMIT top-k pushdown end-to-end against the
 tree-walking oracle, the topk_cap presize/regrowth knob bounds (tested
 like ``binding_stats_capacity``), and the streaming-window grouped
 mode through ``submit(..., stream=)`` / ``stream_result``."""
+import jax
 import numpy as np
 import pytest
 
@@ -88,9 +89,8 @@ def test_spmd_ordered_topk_matches_sim():
     """The capacity-bounded segmented sort lowers under shard_map too:
     spmd Q11 with a bounded topk_cap equals the sim-mode full-width
     run bitwise (cf. test_spmd_grouped_capped_segments)."""
-    from repro import compat
     from repro.data.weather import WeatherSpec, build_database
-    mesh = compat.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), (jax.sharding.AxisType.Auto,))
     db1 = build_database(WeatherSpec(num_stations=5,
                                      years=(1976, 2000),
                                      days_per_year=2),

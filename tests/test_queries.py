@@ -2,6 +2,7 @@
 strategies, jnp + Pallas probes) vs the MRQL-style staged baseline vs
 the Saxon-style tree walker (§5.2; Q9/Q10 are the §6 group-by
 shapes)."""
+import jax
 import numpy as np
 import pytest
 from conftest import canon, check_result
@@ -81,8 +82,7 @@ def test_scan_capacity_overflow_flag(weather_db):
 def test_spmd_single_device(weather_db_small):
     """shard_map path on a 1-device mesh (the 8-device version lives in
     test_distributed.py)."""
-    from repro import compat
-    mesh = compat.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), (jax.sharding.AxisType.Auto,))
     from repro.data.weather import WeatherSpec, build_database
     db1 = build_database(WeatherSpec(num_stations=5, years=(1976, 2000),
                                      days_per_year=2), num_partitions=1)
@@ -92,12 +92,25 @@ def test_spmd_single_device(weather_db_small):
     assert rs.scalar() == pytest.approx(sx.run(ALL["Q4"])[0], rel=1e-3)
 
 
+def test_spmd_mesh_must_match_partitions(weather_db_small):
+    """One partition per device: a 1-device mesh over a 2-partition
+    database raises a typed error — from the executor's spmd compile
+    and from the service constructor — instead of dropping a
+    partition."""
+    from repro.core import InvalidArgumentError, QueryService
+    mesh = jax.make_mesh((1,), ("data",), (jax.sharding.AxisType.Auto,))
+    with pytest.raises(InvalidArgumentError, match="2 partitions"):
+        Executor(weather_db_small).run(compile_query(ALL["Q4"]),
+                                       mode="spmd", mesh=mesh)
+    with pytest.raises(InvalidArgumentError):
+        QueryService(weather_db_small, mode="spmd", mesh=mesh)
+
+
 def test_spmd_grouped_capped_segments(weather_db_small):
     """The capped segment dictionary (all_gather + unique) lowers
     under shard_map too: spmd Q9 with a bounded group_cap equals the
     sim-mode full-dictionary run bitwise."""
-    from repro import compat
-    mesh = compat.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), (jax.sharding.AxisType.Auto,))
     from repro.data.weather import WeatherSpec, build_database
     db1 = build_database(WeatherSpec(num_stations=5, years=(1976, 2000),
                                      days_per_year=2), num_partitions=1)

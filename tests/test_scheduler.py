@@ -4,6 +4,7 @@ adversarial arrival mix, cost-based bucket fitting (DP optimality on
 hand cases + never-worse-than-pow2), and end-to-end submit/drain
 parity on the weather database — including batched dispatch under
 shard_map on a 1-device mesh."""
+import jax
 import pytest
 
 from repro.core import ExecConfig, QueryService
@@ -290,13 +291,12 @@ def test_scheduled_batch_under_shard_map_1dev():
     (num_partitions must equal mesh size) serves a batch through the
     spmd path with results identical to per-request spmd execution.
     (The 8-device version runs in tests/test_distributed.py.)"""
-    from repro import compat
     from repro.data.weather import WeatherSpec, build_database
     db = build_database(WeatherSpec(num_stations=5,
                                     years=(1976, 2000),
                                     days_per_year=2),
                         num_partitions=1)
-    mesh = compat.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), (jax.sharding.AxisType.Auto,))
     texts = variant_grid("Q1", STATIONS, YEARS, 3) \
         + variant_grid("Q3", STATIONS, YEARS, 3)
     svc = QueryService(db, mode="spmd", mesh=mesh)

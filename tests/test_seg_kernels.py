@@ -32,17 +32,13 @@ from repro.kernels.registry import KERNEL_REFS
 from repro.kernels.seg_aggregate import segmented_aggregate
 from repro.kernels.seg_topk import segment_topk
 
-RNG = np.random.default_rng(11)
-
-
-def _agg_case(n, s, nc, rng, tenths=True):
-    """Weather-like aggregate inputs: tenths-valued f32 columns, some
-    NaNs masked out through ``ok``, some invalid rows, some
-    out-of-range segment ids."""
-    vals = jnp.asarray(rng.integers(-400, 400, (n, nc)) / 10.0,
-                       jnp.float32)
-    if not tenths:
-        vals = jnp.asarray(rng.normal(size=(n, nc)), jnp.float32)
+def _agg_case(n, s, nc, rng):
+    """Weather-like aggregate inputs: f32 columns holding integer
+    tenths (the sensor archive's unit), some NaNs masked out through
+    ``ok``, some invalid rows, some out-of-range segment ids. Integer
+    values keep every partial sum exact in f32, so sums compare
+    bitwise whatever order a backend reduces in."""
+    vals = jnp.asarray(rng.integers(-400, 400, (n, nc)), jnp.float32)
     ok = jnp.asarray(rng.random((n, nc)) > 0.1)
     segs = jnp.asarray(rng.integers(-1, s + 2, n), jnp.int32)
     valid = jnp.asarray(rng.random(n) > 0.2)
@@ -68,9 +64,10 @@ def test_registry_refs_resolve():
                                        (256, 32, 256, 1),
                                        (384, 7, 128, 3)])
 def test_segmented_aggregate_kernel_parity(n, s, bn, nc):
-    """Interpreted Pallas kernel == jnp twin, bit for bit: the twin
-    replicates the kernel's blocked accumulation exactly."""
-    vals, ok, segs, valid = _agg_case(n, s, nc, RNG)
+    """Interpreted Pallas kernel == jnp twin, bit for bit: counts and
+    extrema always, sums because the data is integer-valued."""
+    rng = np.random.default_rng([n, s, bn, nc])
+    vals, ok, segs, valid = _agg_case(n, s, nc, rng)
     got = segmented_aggregate(vals, ok, segs, valid, s, block_n=bn,
                               interpret=True)
     want = ref.segmented_aggregate(vals, ok, segs, valid, s,
@@ -123,7 +120,8 @@ def test_ops_dispatch_env(monkeypatch):
     """REPRO_KERNEL_INTERPRET routes the entry point through the
     interpreted kernel; REPRO_FORCE_JNP forces the twin; default CPU
     is the twin. All three agree bitwise."""
-    vals, ok, segs, valid = _agg_case(512, 16, 2, RNG)
+    vals, ok, segs, valid = _agg_case(512, 16, 2,
+                                      np.random.default_rng(12))
     outs = {}
     for env in ({}, {"REPRO_KERNEL_INTERPRET": "1"},
                 {"REPRO_FORCE_JNP": "1"}):
@@ -143,7 +141,8 @@ def test_ops_dispatch_env(monkeypatch):
 def test_ops_count_only_and_topk_dispatch():
     """C == 0 (count-only group-by) returns empty column outputs; the
     top-k entry point matches the lexsort twin."""
-    _, _, segs, valid = _agg_case(256, 8, 1, RNG)
+    rng = np.random.default_rng(13)
+    _, _, segs, valid = _agg_case(256, 8, 1, rng)
     c, s_, mn, mx = ops.segmented_aggregate(
         jnp.zeros((256, 0), jnp.float32), jnp.zeros((256, 0), bool),
         segs, valid, 8)
@@ -154,8 +153,8 @@ def test_ops_count_only_and_topk_dispatch():
     np.add.at(want, np.asarray(segs)[vld], 1.0)
     np.testing.assert_array_equal(np.asarray(c), want)
 
-    keys = (jnp.asarray(RNG.integers(0, 2, 64), jnp.int32),
-            jnp.asarray(RNG.integers(-5, 5, 64), jnp.int32))
+    keys = (jnp.asarray(rng.integers(0, 2, 64), jnp.int32),
+            jnp.asarray(rng.integers(-5, 5, 64), jnp.int32))
     np.testing.assert_array_equal(
         np.asarray(ops.segment_topk(keys, 8)),
         np.asarray(ref.segment_topk(keys, 8)))
@@ -208,8 +207,7 @@ def test_property_empty_segments(seed):
     assert np.all(np.asarray(got[2])[empty] == np.inf)
     assert np.all(np.asarray(got[3])[empty] == -np.inf)
     np.testing.assert_array_equal(np.asarray(got[0]), want[0])
-    np.testing.assert_allclose(np.asarray(got[1]), want[1],
-                               rtol=1e-6, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(got[1]), want[1])
     np.testing.assert_array_equal(np.asarray(got[2]), want[2])
     np.testing.assert_array_equal(np.asarray(got[3]), want[3])
 
@@ -218,9 +216,9 @@ def test_property_empty_segments(seed):
 @pytest.mark.parametrize("seed", range(3))
 def test_property_single_mega_segment(seed):
     """Every row in one segment: count equals the valid-row count and
-    the sum accumulates in row order (bitwise vs the same-order host
-    fold in f64 is too strict for f32 — compare against the f32
-    sequential fold instead)."""
+    the sum equals the f32 sequential fold — exactly, because the
+    integer-valued data leaves no rounding for the reduction order to
+    change."""
     rng = np.random.default_rng(100 + seed)
     n, s = 512, 8
     vals, ok, _, valid = _agg_case(n, s, 1, rng)
@@ -236,11 +234,7 @@ def test_property_single_mega_segment(seed):
         if vld[i]:
             acc = np.float32(acc + (vn[i] if okn[i]
                                     else np.float32(0.0)))
-    # blocked accumulation can associate differently from the strict
-    # sequential fold only by rounding; tenths-valued weather data
-    # stays exact (ISSUE 8's bit-parity domain)
-    np.testing.assert_allclose(float(sums[5, 0]), float(acc),
-                               rtol=1e-6, atol=1e-4)
+    assert float(sums[5, 0]) == float(acc)
     assert np.all(np.asarray(counts)[np.arange(s) != 5] == 0)
 
 
