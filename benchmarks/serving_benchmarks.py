@@ -33,8 +33,7 @@ Four suites share one record (BENCH_serving.json):
                 a 64-request (4 in smoke) multi-tenant scheduled
                 trace exports through ``Tracer.chrome_trace`` and
                 must validate against the Chrome/Perfetto
-                trace_event schema (full runs write the artifact to
-                BENCH_obs_trace.json)
+                trace_event schema
 
 Three serving modes are measured per suite:
 
@@ -515,7 +514,7 @@ def serving_obs(variants: int = 64, repeats: int = 3,
     small to time stably); the gate raises BEFORE the json write. A
     scheduled multi-tenant trace (64 requests; 4 in smoke) is exported
     via ``chrome_trace`` on both clocks and validated against the
-    trace_event schema; full runs write BENCH_obs_trace.json."""
+    trace_event schema in memory."""
     from repro.core.obs.trace import Tracer, validate_trace_events
 
     spec = SMOKE_SPEC if smoke else FULL_SPEC
@@ -596,11 +595,6 @@ def serving_obs(variants: int = 64, repeats: int = 3,
             f"tracing-off warm QPS is {1 - off_vs_null:.1%} below the "
             f"NULL-tracer baseline (allowed {tol:.0%}) — the "
             f"instrumentation leaked onto the warm path")
-    if not smoke:
-        with open("BENCH_obs_trace.json", "w") as f:
-            json.dump(ev_virtual, f, indent=1)
-            f.write("\n")
-        print("# wrote BENCH_obs_trace.json")
     _merge_record(out_path, "obs", results)
     return results
 

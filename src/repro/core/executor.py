@@ -29,6 +29,7 @@ from jax import lax
 from repro.core import algebra as A
 from repro.core import xdm
 from repro.core.errors import InvalidArgumentError
+from repro.core.obs import trace as obs_trace
 from repro.core.physical import (Col, ExprEval, Tile, _gather,
                                  device_tables, path_match_mask,
                                  rows_from_mask, topk_rows)
@@ -179,13 +180,27 @@ class EvalCtx:
 
 
 class Comm:
-    """Collective surface, identical under vmap and shard_map."""
+    """Collective surface, identical under vmap and shard_map.
+
+    Every collective runs under the ``exchange`` named scope, so a
+    device profile attributes its operations to the exchange, and
+    adds its logical traffic to ``bytes`` at trace time: a b-byte
+    operand over P partitions counts b*(P-1)."""
 
     def __init__(self, axis: Optional[str]):
         self.axis = axis
+        self.bytes = 0
+
+    def _count(self, x) -> None:
+        x = jnp.asarray(x)
+        self.bytes += x.size * x.dtype.itemsize * (self.size() - 1)
 
     def psum(self, x):
-        return lax.psum(x, self.axis) if self.axis else x
+        if not self.axis:
+            return x
+        self._count(x)
+        with jax.named_scope("exchange"):
+            return lax.psum(x, self.axis)
 
     def pmax(self, x):
         if not self.axis:
@@ -200,7 +215,9 @@ class Comm:
     def all_gather(self, x):
         if not self.axis:
             return x[None] if hasattr(x, "ndim") else jnp.asarray(x)[None]
-        return lax.all_gather(x, self.axis)
+        self._count(x)
+        with jax.named_scope("exchange"):
+            return lax.all_gather(x, self.axis)
 
     def por(self, x):
         return self.psum(x.astype(I32)) > 0
@@ -456,6 +473,7 @@ class Executor:
         prof_meta: Optional[dict] = {} if profile else None
         op_index = ({id(op): i for i, op in enumerate(A.walk(plan))}
                     if profile else None)
+        traced: dict = {}
         jit = partial(jax.jit, donate_argnums=(0,)) if donate else jax.jit
         if batch is not None and not param_specs:
             raise ValueError("batched compilation needs parameters")
@@ -470,7 +488,11 @@ class Executor:
             else:
                 ctx = EvalCtx(cfg)
             tile = self._eval(plan, ev, comm, None, ctx)
-            return self._outputs(plan, tile, ev, schema, ctx)
+            with jax.named_scope("outputs"):
+                out = self._outputs(plan, tile, ev, schema, ctx)
+            # one request's collectives; a batched run moves B of them
+            traced["exchange_bytes"] = comm.bytes * (batch or 1)
+            return out
 
         if mode == "sim":
             if param_specs:
@@ -490,7 +512,8 @@ class Executor:
                 out_fn = self._aot_compile(out_fn, param_specs, batch)
             return CompiledPlan(out_fn, schema, plan, cfg, mode,
                                 donated=donate, param_specs=param_specs,
-                                batch=batch, profile_meta=prof_meta)
+                                batch=batch, profile_meta=prof_meta,
+                                traced=traced)
         if mode == "spmd":
             from jax.sharding import PartitionSpec as P
 
@@ -532,7 +555,8 @@ class Executor:
                 out_fn = self._aot_compile(out_fn, param_specs, batch)
             return CompiledPlan(out_fn, schema, plan, cfg, mode,
                                 donated=donate, param_specs=param_specs,
-                                batch=batch, profile_meta=prof_meta)
+                                batch=batch, profile_meta=prof_meta,
+                                traced=traced)
         raise ValueError(mode)
 
     def _aot_compile(self, jitted, param_specs: tuple,
@@ -567,18 +591,23 @@ class Executor:
                 raise ValueError(
                     f"plan expects {len(cp.param_specs)} parameters, "
                     f"got {None if params is None else len(params)}")
-            out = cp.fn(self.tables, tuple(params))
+            args = (self.tables, tuple(params))
         else:
-            out = cp.fn(self.tables)
+            args = (self.tables,)
+        tr = obs_trace.current()
+        with tr.span("launch", cat="service"):
+            out = cp.fn(*args)
         # a trace/compile error above consumed nothing (executor stays
         # usable); once dispatch returned, buffers are donated even if
         # the fetch below fails — flip the flags in between
         if cp.donated:
             cp.spent = True
             self._tables_donated = True
-        raw = jax.device_get(out)
-        return ResultSet(self.db, cp.plan, raw, cp.schema,
-                         profile_meta=cp.profile_meta)
+        raw, nbytes = _fetch(out, tr)
+        rs = ResultSet(self.db, cp.plan, raw, cp.schema,
+                       profile_meta=cp.profile_meta)
+        rs.fetch_bytes = nbytes
+        return rs
 
     def run_compiled_batch(self, cp: "CompiledPlan", stacked: tuple,
                            count: int) -> list["ResultSet"]:
@@ -588,20 +617,25 @@ class Executor:
         request."""
         assert cp.batch is not None and count <= cp.batch
         self._check_runnable(cp)
-        out = cp.fn(self.tables, stacked)
+        tr = obs_trace.current()
+        with tr.span("launch", cat="service"):
+            out = cp.fn(self.tables, stacked)
         if cp.donated:
             cp.spent = True
             self._tables_donated = True
-        raw = jax.device_get(out)
+        raw, nbytes = _fetch(out, tr)
 
         def take(v, b):
             return tuple(d[b] for d in v) if isinstance(v, tuple) \
                 else v[b]
 
-        return [ResultSet(self.db, cp.plan,
-                          {k: take(v, b) for k, v in raw.items()},
-                          cp.schema, profile_meta=cp.profile_meta)
-                for b in range(count)]
+        rss = [ResultSet(self.db, cp.plan,
+                         {k: take(v, b) for k, v in raw.items()},
+                         cp.schema, profile_meta=cp.profile_meta)
+               for b in range(count)]
+        for rs in rss:
+            rs.fetch_bytes = nbytes     # the batch's one shared copy
+        return rss
 
     def _check_runnable(self, cp: "CompiledPlan") -> None:
         if self._tables_donated:
@@ -695,6 +729,12 @@ class Executor:
 
     def _eval_group_by(self, op: "A.GroupBy", ev, comm, nts_input,
                        ctx: EvalCtx) -> Tile:
+        t = self._eval(op.child, ev, comm, nts_input, ctx)
+        with jax.named_scope("group_by"):
+            return self._group_by(op, ev, comm, t, ctx)
+
+    def _group_by(self, op: "A.GroupBy", ev, comm, t: Tile,
+                  ctx: EvalCtx) -> Tile:
         """Keyed two-step aggregation (XQuery 3.0 group-by, the
         paper's §6 future work): grouping keys are dictionary-encoded
         strings, so the segment space is the string dictionary; the
@@ -723,7 +763,6 @@ class Executor:
         Both paths read the same ``group_cap`` and raise the same
         ``overflow_group_cap`` flag: the knob changes implementation,
         never capacity semantics (core.analysis.capflow's contract)."""
-        t = self._eval(op.child, ev, comm, nts_input, ctx)
         key = ev.eval(op.key_expr, t.cols)
         sid = ev.atom_sid(key)
         dict_size = len(self.db.strings)
@@ -862,6 +901,12 @@ class Executor:
 
     def _eval_orderby(self, op: "A.OrderBy", ev, comm, nts_input,
                       ctx: EvalCtx, limit: Optional[int]) -> Tile:
+        t = self._eval(op.child, ev, comm, nts_input, ctx)
+        with jax.named_scope("order_by"):
+            return self._order_by(op, ev, t, ctx, limit)
+
+    def _order_by(self, op: "A.OrderBy", ev, t: Tile, ctx: EvalCtx,
+                  limit: Optional[int]) -> Tile:
         """Capacity-bounded segmented sort over the (grouped) tuple
         stream — ORDER BY, with the top-k pushdown when a LIMIT sits
         directly above. The sorted tile is ``topk_cap`` wide (None:
@@ -871,7 +916,6 @@ class Executor:
         ran over. Too-small caps raise ``overflow_topk_cap`` (its own
         rung in the service regrowth ladder) — never a silent
         truncation of the ranking."""
-        t = self._eval(op.child, ev, comm, nts_input, ctx)
         sort_keys: list[tuple] = []
         for e, desc in op.keys:
             col = ev.eval(e, t.cols)
@@ -1064,9 +1108,10 @@ class Executor:
         else:
             raise ValueError(cfg.join_strategy)
 
-        pos, matched, bovf = hash_join_probe(
-            bkeys, bvalid, pkeys, pvalid, cfg.join_bucket,
-            use_pallas=cfg.use_pallas_join)
+        with jax.named_scope("join_probe"):
+            pos, matched, bovf = hash_join_probe(
+                bkeys, bvalid, pkeys, pvalid, cfg.join_bucket,
+                use_pallas=cfg.use_pallas_join)
         ctx.note("overflow_join", bovf)
 
         def attach(c: Col) -> Col:
@@ -1163,6 +1208,21 @@ class Executor:
 # Result extraction (host)
 # ---------------------------------------------------------------------------
 
+def _fetch(out, tr) -> tuple[Any, int]:
+    """A run's output tiles copied to the host, and their bytes. An
+    enabled tracer splits the copy into ``wait`` (the device finishing
+    the program) and ``fetch`` (the transfer itself); otherwise it is
+    the one ``device_get``."""
+    if tr.enabled:
+        with tr.span("wait", cat="service"):
+            jax.block_until_ready(out)
+        with tr.span("fetch", cat="service"):
+            raw = jax.device_get(out)
+    else:
+        raw = jax.device_get(out)
+    return raw, sum(np.asarray(x).nbytes for x in jax.tree.leaves(raw))
+
+
 @dataclasses.dataclass
 class CompiledPlan:
     fn: Callable
@@ -1176,12 +1236,26 @@ class CompiledPlan:
     batch: Optional[int] = None           # B of a batched dispatch fn
     profile_meta: Optional[dict] = None   # profile=True: op order,
     #                                       filled at trace time
+    traced: dict = dataclasses.field(default_factory=dict)
+    #                                       facts filled at trace time
+
+    @property
+    def exchange_bytes(self) -> int:
+        """Logical bytes the plan's collectives move in one run (0
+        until the fn is traced; AOT plans are traced at compile)."""
+        return self.traced.get("exchange_bytes", 0)
 
 
 class ResultSet:
     """Host-side result decoding: rows of python values, plus node
     fingerprints (concatenated descendant text, document order) so
-    differential tests can compare against the tree-walking baseline."""
+    differential tests can compare against the tree-walking baseline.
+
+    A result keeps the tracer it was produced under (``rows()`` runs
+    in its ``decode`` span), the bytes of the device-to-host copy it
+    came from (``fetch_bytes``; a batch's results share one copy), and
+    ``on_decode``, called with the row count the first time ``rows()``
+    decodes (the service sets it to count decoded rows)."""
 
     def __init__(self, db: xdm.Database, plan: A.Op, raw: dict,
                  schema: dict[int, tuple], profile_meta: dict = None):
@@ -1190,6 +1264,9 @@ class ResultSet:
         self.raw = raw
         self.schema = schema
         self.profile_meta = profile_meta
+        self.tracer = obs_trace.current()
+        self.fetch_bytes = 0
+        self.on_decode: Optional[Callable[[int], None]] = None
         self.overflow = bool(np.any(raw["overflow"]))
         # per-stage flags (absent in pre-refactor raw dicts)
         for flag in OVERFLOW_FLAGS.values():    # overflow_scan, ...
@@ -1226,17 +1303,21 @@ class ResultSet:
 
     def rows(self) -> list[tuple]:
         assert isinstance(self.plan, A.DistributeResult)
-        valid = np.asarray(self.raw["valid"])       # [P, T]
-        npart, t = valid.shape
-        out = []
-        for p in range(npart):
-            for r in range(t):
-                if not valid[p, r]:
-                    continue
-                row = []
-                for v in self.plan.vars:
-                    row.append(self._value(v, p, r))
-                out.append(tuple(row))
+        with self.tracer.span("decode", cat="service"):
+            valid = np.asarray(self.raw["valid"])       # [P, T]
+            npart, t = valid.shape
+            out = []
+            for p in range(npart):
+                for r in range(t):
+                    if not valid[p, r]:
+                        continue
+                    row = []
+                    for v in self.plan.vars:
+                        row.append(self._value(v, p, r))
+                    out.append(tuple(row))
+        if self.on_decode is not None:
+            self.on_decode(len(out))
+            self.on_decode = None           # a result's rows count once
         return out
 
     def _value(self, v: int, p: int, r: int):

@@ -17,8 +17,22 @@ rewrite.<stage>       rewrite    rewrite.engine.optimize, one span per
 rewrite-rule          rewrite    instant per rule firing (args: rule)
 compile               service    QueryService.compiled on cache miss
                                  (trace+jit of one cap/batch variant)
-execute               service    QueryService.execute (regrowth ladder
-                                 included)
+execute               service    QueryService.execute, the whole call:
+                                 prepare (memo miss), then the regrowth
+                                 ladder
+bind                  service    QueryService.execute/serve_group:
+                                 parameter binding, binding stats, the
+                                 capacity config and the plan-cache
+                                 lookup (a miss nests ``compile``)
+launch                service    Executor.run_compiled(_batch): the
+                                 compiled call, which returns once the
+                                 program is enqueued on the device
+wait                  service    the same: until the device finishes
+                                 the program (enabled tracers only)
+fetch                 service    the same: the device-to-host copy of
+                                 the output tiles (enabled tracers only)
+decode                service    ResultSet.rows (host rows from the
+                                 fetched tiles)
 serve-group           service    QueryService.serve_group (one batched
                                  dispatch + its regrowth retries)
 regrow-retry          service    instant per regrowth rung (args: the
@@ -46,11 +60,17 @@ facts (never wall durations), so replaying the same seeded trace
 yields byte-identical logs; ``chrome_trace()`` exports either clock as
 Chrome/Perfetto ``trace_event`` JSON.
 
-No jax at import time, and zero cost when tracing is off: the module
-ships a ``NULL_TRACER`` whose ``span()`` returns one shared no-op
-context manager — the service default, i.e. the pre-instrumentation
-warm path. Nothing here ever runs inside jitted code; every emit site
-sits at a host-side stage boundary.
+An enabled tracer also opens a ``jax.profiler.TraceAnnotation`` named
+``vxq.<name>`` for every span, so under ``jax.profiler`` the stages
+land in the same profile as the device's operations, on the same
+clock. Instant events stay in ``records`` only.
+
+No jax at import time (the enabled path imports it on first use), and
+zero cost when tracing is off: the module ships a ``NULL_TRACER``
+whose ``span()`` returns one shared no-op context manager — the
+service default, i.e. the pre-instrumentation warm path. Nothing here
+ever runs inside jitted code; every emit site sits at a host-side
+stage boundary.
 """
 from __future__ import annotations
 
@@ -59,6 +79,20 @@ import hashlib
 import json
 import time
 from typing import Any, Optional
+
+
+#: prefix of the profiler annotation that mirrors each span
+PROFILER_PREFIX = "vxq."
+
+_annotation = None      # jax.profiler.TraceAnnotation, bound on first use
+
+
+def _profiler_annotation(name: str):
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation(PROFILER_PREFIX + name)
 
 
 def sig_digest(sig) -> str:
@@ -73,10 +107,11 @@ class Span:
 
     ``wall0/wall1`` are ``time.perf_counter`` stamps; ``vt0/vt1`` are
     virtual-clock stamps, present only when the tracer had a clock
-    bound while the span was open."""
+    bound while the span was open. ``annotation`` is the open
+    profiler annotation that mirrors the span."""
 
     __slots__ = ("tracer", "sid", "parent", "name", "cat", "kind",
-                 "wall0", "wall1", "vt0", "vt1", "args")
+                 "wall0", "wall1", "vt0", "vt1", "args", "annotation")
 
     def __init__(self, tracer: "Tracer", sid: int, name: str,
                  cat: str, args: dict):
@@ -89,6 +124,7 @@ class Span:
         self.wall0 = self.wall1 = None
         self.vt0 = self.vt1 = None
         self.args = args
+        self.annotation = None
 
     def set(self, **kw) -> None:
         """Attach args to an open span. Keep values deterministic
@@ -110,10 +146,14 @@ class Span:
             self.vt0 = tr.clock.now()
         tr._stack.append(self.sid)
         tr._record(self)
+        self.annotation = _profiler_annotation(self.name)
+        self.annotation.__enter__()
         return self
 
     def __exit__(self, et, ev, tb):
         tr = self.tracer
+        self.annotation.__exit__(et, ev, tb)
+        self.annotation = None
         self.wall1 = time.perf_counter()  # lint: allow(DET001)
         if tr.clock is not None:
             self.vt1 = tr.clock.now()

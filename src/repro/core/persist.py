@@ -24,15 +24,17 @@ missed, so a mismatched environment is provably never served.
 File format (all-or-nothing, torn writes detected):
 
     MAGIC(8) | sha256(body)(32) | body = pickle({fingerprint, key,
-                                                 schema, payload,
-                                                 in_tree, out_tree})
+                                                 schema, exchange_bytes,
+                                                 payload, in_tree,
+                                                 out_tree})
 
 ``payload`` is the XLA executable bytes from
 ``jax.experimental.serialize_executable.serialize``; ``in_tree`` /
 ``out_tree`` are its pickled PyTreeDefs. ``schema`` is the
 ``CompiledPlan`` column schema captured at trace time — strings can't
 flow through the compiled fn, so the schema must persist beside the
-executable. Every failure mode — missing file, torn write, checksum
+executable; ``exchange_bytes`` (the logical bytes the plan's
+collectives move per run) is fixed at trace time the same way. Every failure mode — missing file, torn write, checksum
 mismatch, unpicklable body, foreign format version, fingerprint
 mismatch, undeserializable executable — degrades to a normal
 trace+compile; corruption deletes the entry so the next lookup is a
@@ -56,7 +58,7 @@ from typing import Optional
 
 #: bump when the entry layout changes — old files then read as
 #: fingerprint mismatches (invalidated, recompiled, overwritten)
-FORMAT_VERSION = 1
+FORMAT_VERSION = 2
 
 _MAGIC = b"RPLANC01"
 _SUFFIX = ".plan"
@@ -158,6 +160,7 @@ def pack_compiled(cp) -> Optional[dict]:
         payload, in_tree, out_tree = jse.serialize(cp.fn)
         return {
             "schema": dict(cp.schema),
+            "exchange_bytes": cp.exchange_bytes,
             "payload": payload,
             "in_tree": pickle.dumps(in_tree),
             "out_tree": pickle.dumps(out_tree),

@@ -434,16 +434,19 @@ def rows_from_mask(mask: jnp.ndarray, cap: int
     position whose running set-bit count reaches j+1. Bit-identical
     indices, but scatter-free — XLA CPU lowers the nonzero scatter to
     a serial while loop that dominated every query's warm latency
-    (the ordered-suite pushdown regression)."""
+    (the ordered-suite pushdown regression). Its operations run under
+    the ``rows_from_mask`` named scope, so a device profile can tell
+    compaction time from the rest of the plan."""
     n = mask.shape[0]
     cap = min(cap, n)
-    pos = jnp.cumsum(mask.astype(I32))
-    total = pos[-1]
-    idx = jnp.searchsorted(pos, jnp.arange(1, cap + 1, dtype=I32))
-    valid = jnp.arange(cap) < total
-    idx = jnp.where(valid, idx, NEG)
-    overflow = total > cap
-    return idx.astype(I32), valid, overflow
+    with jax.named_scope("rows_from_mask"):
+        pos = jnp.cumsum(mask.astype(I32))
+        total = pos[-1]
+        idx = jnp.searchsorted(pos, jnp.arange(1, cap + 1, dtype=I32))
+        valid = jnp.arange(cap) < total
+        idx = jnp.where(valid, idx, NEG)
+        overflow = total > cap
+        return idx.astype(I32), valid, overflow
 
 
 def topk_rows(sort_keys: list[tuple[jnp.ndarray, bool]],

@@ -40,6 +40,7 @@ compiled executable and batch through ``execute_batch``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Sequence
 
 import numpy as np
@@ -88,6 +89,12 @@ class PreparedQuery:
     @property
     def num_params(self) -> int:
         return len(self.specs)
+
+    @functools.cached_property
+    def digest(self) -> str:
+        """``sig_digest`` of the signature, hashed once per prepared
+        query instead of on every request."""
+        return obs_trace.sig_digest(self.signature)
 
 
 # ---------------------------------------------------------------------------

@@ -228,6 +228,15 @@ class ServiceStats:
     # level-1 plan cache and stays for compatibility; the rest used
     # to evict silently
     evictions_by_cache: dict = dataclasses.field(default_factory=dict)
+    # the served path's stages: query texts that missed the prepare
+    # memo and ran the front end (parse, rewrite, lift, verify); bytes
+    # of the output tiles copied device-to-host; rows ResultSet.rows()
+    # produced; and the logical bytes the runs' collectives moved
+    # (CompiledPlan.exchange_bytes, counted once per device run)
+    prepares: int = 0
+    fetch_bytes: int = 0
+    rows_decoded: int = 0
+    exchange_bytes: int = 0
 
     @property
     def hit_rate(self) -> float:
@@ -432,8 +441,8 @@ class QueryService:
                         self.tracer.span("prepare", cat="prepare") as sp:
                     pq = self._prepare_plan(optimize(translate(query)),
                                             query)
-                    sp.set(sig=sig_digest(pq.signature),
-                           params=len(pq.specs))
+                    sp.set(sig=pq.digest, params=len(pq.specs))
+                self.stats.prepares += 1
                 if len(self._prepared_memo) >= 4096:
                     # adversarially unique query texts must not grow
                     # host memory forever; a flush re-prepares
@@ -576,6 +585,7 @@ class QueryService:
             return None
         try:
             fn = persist_mod.load_executable(entry)
+            exchange_bytes = int(entry["exchange_bytes"])
         except Exception:
             self._persist.invalidate(pkey)
             self.stats.persist_invalidations += 1
@@ -586,7 +596,8 @@ class QueryService:
         return CompiledPlan(fn, entry["schema"], plan, config=rcfg,
                             mode=self.mode,
                             param_specs=tuple(param_specs),
-                            batch=batch)
+                            batch=batch,
+                            traced={"exchange_bytes": exchange_bytes})
 
     def _persist_store(self, cp: CompiledPlan, sig: str,
                        batch: Optional[int]) -> None:
@@ -679,6 +690,20 @@ class QueryService:
         self.tracer.event("regrow-retry", cat="service",
                           sig=sig_digest(sig),
                           **{cap: n for cap, _, n in grown})
+
+    def _note_run(self, cp: CompiledPlan,
+                  rss: Sequence[ResultSet]) -> None:
+        """Count one device run of ``cp`` that produced ``rss`` (one
+        result, or a batch's results sharing one fetch), and have each
+        result report the rows its first ``rows()`` decodes."""
+        self.stats.runs += 1
+        self.stats.fetch_bytes += rss[0].fetch_bytes
+        self.stats.exchange_bytes += cp.exchange_bytes
+        for rs in rss:
+            rs.on_decode = self._note_decoded
+
+    def _note_decoded(self, n: int) -> None:
+        self.stats.rows_decoded += n
 
     def _note_binding(self, sig: str, values: tuple) -> None:
         key = (sig, values)
@@ -883,20 +908,25 @@ class QueryService:
         workload pays a growth step once). ``bindings`` overrides the
         prepared query's parameter values (defaults: the literals of
         the source query)."""
-        pq = self.prepare(query)
-        values = self._values_for(pq, bindings)
-        params = bind_params(self.db, pq.specs, values)
-        self.stats.executions += 1
-        self._note_binding(pq.signature, values)
-        cfg = (self._good_cfg.get(pq.signature)
-               or self._presized_config(pq.plan))
-        with self.tracer.span("execute", cat="service") as span:
-            span.set(sig=sig_digest(pq.signature))
-            for attempt in range(self.max_retries + 1):
+        tr = self.tracer
+        # the executor's launch/wait/fetch spans and the ResultSet's
+        # decode span go through the ambient tracer
+        with obs_trace.using(tr), \
+                tr.span("execute", cat="service") as span:
+            pq = self.prepare(query)
+            span.set(sig=pq.digest)
+            with tr.span("bind", cat="service"):
+                values = self._values_for(pq, bindings)
+                params = bind_params(self.db, pq.specs, values)
+                self.stats.executions += 1
+                self._note_binding(pq.signature, values)
+                cfg = (self._good_cfg.get(pq.signature)
+                       or self._presized_config(pq.plan))
                 cp = self.compiled(pq.plan, cfg, sig=pq.signature,
                                    param_specs=pq.specs)
+            for attempt in range(self.max_retries + 1):
                 rs = self.executor.run_compiled(cp, params=params)
-                self.stats.runs += 1
+                self._note_run(cp, [rs])
                 if not rs.overflow:
                     self._note_good_cfg(pq.signature, cfg)
                     return rs
@@ -906,6 +936,9 @@ class QueryService:
                 self._note_regrow(pq.signature, cfg, grown)
                 cfg = grown
                 self.stats.retries += 1
+                with tr.span("bind", cat="service"):
+                    cp = self.compiled(pq.plan, cfg, sig=pq.signature,
+                                       param_specs=pq.specs)
         raise QueryOverflowError(
             f"still overflowing after {self.max_retries} regrowth "
             f"retries (scan_cap={cfg.scan_cap}, "
@@ -927,23 +960,26 @@ class QueryService:
         executor vmaps the batch axis outside the mesh axis)."""
         assert pq.specs, "parameterless plans have nothing to stack"
         sig = pq.signature
-        values_list = [tuple(v) for v in values_list]
-        bound = [bind_params(self.db, pq.specs, v) for v in values_list]
-        if bucket is None:
-            bucket = _next_pow2(len(bound))
-        assert bucket >= len(bound)
-        stacked = stack_params(bound, bucket)
-        cfg = (self._good_cfg.get(sig)
-               or self._presized_config(pq.plan))
-        with self.tracer.span("serve-group", cat="service") as span:
-            span.set(sig=sig_digest(sig), requests=len(bound),
-                     bucket=bucket)
-            for attempt in range(self.max_retries + 1):
+        tr = self.tracer
+        with obs_trace.using(tr), \
+                tr.span("serve-group", cat="service") as span:
+            with tr.span("bind", cat="service"):
+                values_list = [tuple(v) for v in values_list]
+                bound = [bind_params(self.db, pq.specs, v)
+                         for v in values_list]
+                if bucket is None:
+                    bucket = _next_pow2(len(bound))
+                assert bucket >= len(bound)
+                stacked = stack_params(bound, bucket)
+                cfg = (self._good_cfg.get(sig)
+                       or self._presized_config(pq.plan))
                 cp = self.compiled(pq.plan, cfg, sig=sig,
                                    param_specs=pq.specs, batch=bucket)
+            span.set(sig=pq.digest, requests=len(bound), bucket=bucket)
+            for attempt in range(self.max_retries + 1):
                 rss = self.executor.run_compiled_batch(cp, stacked,
                                                        len(bound))
-                self.stats.runs += 1
+                self._note_run(cp, rss)
                 if not any(rs.overflow for rs in rss):
                     self._note_good_cfg(sig, cfg)
                     self.stats.executions += len(bound)
@@ -958,6 +994,10 @@ class QueryService:
                 self._note_regrow(sig, cfg, grown)
                 cfg = grown
                 self.stats.retries += 1
+                with tr.span("bind", cat="service"):
+                    cp = self.compiled(pq.plan, cfg, sig=sig,
+                                       param_specs=pq.specs,
+                                       batch=bucket)
         raise QueryOverflowError(
             f"batch still overflowing after {self.max_retries} "
             f"regrowth retries (scan_cap={cfg.scan_cap}, "

@@ -236,7 +236,7 @@ class ServingRuntime:
         with self.tracer.span("admit", cat="serving", tenant=tenant,
                               seq=self.stats.submitted) as sp:
             pq = self.service.prepare(query)
-            sp.set(sig=sig_digest(pq.signature))
+            sp.set(sig=pq.digest)
             values = self.service._values_for(pq, bindings)
         if stream is not None:
             spec = group_spec_of(pq.plan)   # raises on non-mergeable

@@ -115,6 +115,7 @@ def block_join_probe(build_keys: tuple[jax.Array, ...],
         scratch_shapes=[pltpu.VMEM((nkeys, bp, bb), jnp.int32),
                         pltpu.VMEM((bp, bb), jnp.int32)],
         interpret=interpret,
+        name="hash_join_probe",
     )(*[_row(k, np_) for k in probe_keys],
       *[_row(k, nb) for k in build_keys],
       _row(probe_valid, np_), _row(build_valid, nb))

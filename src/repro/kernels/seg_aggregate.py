@@ -115,5 +115,6 @@ def segmented_aggregate(values: jax.Array, ok: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((spad, 1), jnp.float32)]
         + [jax.ShapeDtypeStruct((spad, nc), jnp.float32)] * 3,
         interpret=interpret,
+        name="seg_aggregate",
     )(seg, vals_t, ok_t)
     return cnt[:s, 0], sums[:s], mins[:s], maxs[:s]

@@ -99,5 +99,6 @@ def segment_topk(keys: tuple[jax.Array, ...], cap: int, *,
         out_specs=pl.BlockSpec((1, cpad), lambda: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, cpad), jnp.int32),
         interpret=interpret,
+        name="seg_topk",
     )(*padded)
     return out[0, :cap]

@@ -16,13 +16,19 @@ Covers the tentpole contracts:
   scheduled paths;
 * SLO misses carry per-tenant and per-cause attribution;
 * the OBS001/OBS002 lint keeps stats increments and the metrics
-  registry in sync.
+  registry in sync;
+* ``execute`` runs its stages (prepare, bind, launch, wait, fetch,
+  then decode) in spans that an enabled tracer mirrors into the JAX
+  profiler, costs one ``device_get`` with tracing off, and counts
+  prepares, fetched bytes, decoded rows and exchange bytes.
 """
 import json
 import math
 import os
 import random
 
+import jax
+import numpy as np
 import pytest
 
 import repro
@@ -515,3 +521,153 @@ def test_obs002_flags_stale_registration(tmp_path):
     found = lint_metrics(str(tmp_path))
     assert [f.code for f in found] == ["OBS002"]
     assert "phantom" in found[0].message
+
+
+# ---------------------------------------------------------------------------
+# the served path's stages: spans on the profiler clock, and counters
+# ---------------------------------------------------------------------------
+
+STAGES = ["prepare", "bind", "launch", "wait", "fetch"]
+
+
+def _q4(dtype: str) -> str:
+    """Q4 with another data type: a new text (a prepare-memo miss)
+    of the same template (a plan-cache hit)."""
+    return ALL["Q4"].replace('"TMAX"', f'"{dtype}"')
+
+
+def test_execute_stages_nest_in_the_tracer(weather_db_small):
+    tr = Tracer()
+    svc = QueryService(weather_db_small, tracer=tr)
+    svc.execute(ALL["Q4"]).rows()          # warm: compile outside
+    tr.clear()
+    rs = svc.execute(_q4("TMIN"))
+    rs.rows()                              # outside the service
+    top = tr.records[0]
+    assert top.name == "execute" and top.parent is None
+    assert [s.name for s in tr.records
+            if s.parent == top.sid] == STAGES
+    assert "verify" in [s.name for s in tr.records]    # in prepare
+    assert tr.records[-1].name == "decode"
+    assert tr.records[-1].parent is None
+    assert top.args["sig"] == svc.prepare(_q4("TMIN")).digest
+
+
+def test_spans_mirror_into_the_profiler(weather_db_small, tmp_path):
+    """An enabled tracer's spans land in the profiler's trace as
+    ``vxq.<span>`` annotations: ``execute`` holds the stages in order,
+    and ``decode`` follows it."""
+    import jax
+    from jax.profiler import ProfileData
+    svc = QueryService(weather_db_small, tracer=Tracer())
+    svc.execute(ALL["Q4"]).rows()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        svc.execute(_q4("PRCP")).rows()
+    finally:
+        jax.profiler.stop_trace()
+    paths = list(tmp_path.glob("**/*.xplane.pb"))
+    assert len(paths) == 1
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+             for plane in ProfileData.from_file(str(paths[0])).planes
+             if plane.name == "/host:CPU"
+             for line in plane.lines if line.name == "python"
+             for e in line.events if e.name.startswith("vxq.")]
+    by = {}
+    for name, a, b in spans:
+        by.setdefault(name[len("vxq."):], []).append((a, b))
+    (ea, eb), = by["execute"]
+    at = ea
+    for stage in STAGES:
+        (a, b), = by[stage]
+        assert ea <= a and b <= eb, stage
+        assert a >= at, stage              # in order
+        at = b
+    (da, db), = by["decode"]
+    assert da >= eb
+
+
+def test_null_tracer_execute_is_one_device_get(weather_db_small,
+                                               monkeypatch):
+    """Tracing off, ``execute`` records nothing and makes the calls it
+    always made: one ``device_get`` and no ``block_until_ready``."""
+    import jax
+    svc = QueryService(weather_db_small)
+    assert svc.tracer is NULL_TRACER
+    svc.execute(ALL["Q4"]).rows()
+    calls = []
+    get, wait = jax.device_get, jax.block_until_ready
+    monkeypatch.setattr(jax, "device_get",
+                        lambda x: calls.append("get") or get(x))
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: calls.append("wait") or wait(x))
+    svc.execute(_q4("TMIN")).rows()
+    assert calls == ["get"]
+    assert NULL_TRACER.records == []
+
+
+def test_stage_counters_match_hand_counts(weather_db_small):
+    svc = QueryService(weather_db_small)
+    snap = svc.stats.snapshot()
+    rs = svc.execute(ALL["Q4"])
+    rows = rs.rows()
+    svc.execute(ALL["Q4"])                 # memo hit: no prepare
+    rs3 = svc.execute(_q4("TMIN"))
+    d = svc.stats.diff(snap)
+    assert d.prepares == 2
+    assert d.rows_decoded == len(rows) == 1
+    fetched = [sum(np.asarray(x).nbytes for x in jax.tree.leaves(r.raw))
+               for r in (rs, rs3)]
+    assert rs.fetch_bytes == fetched[0] > 0
+    # three runs of one plan: the same tiles each time
+    assert d.fetch_bytes == 3 * fetched[0] == 3 * fetched[1]
+    # Q4 is max(...): one pmax, an all_gather of one f32 over 2
+    # partitions, 4 bytes to the one other partition, per run
+    assert d.exchange_bytes == 3 * 4
+
+
+def test_exchange_bytes_per_partition_count():
+    from repro.data.weather import WeatherSpec, build_database
+    spec = WeatherSpec(num_stations=5, years=(1976, 2000),
+                       days_per_year=2)
+    got = {}
+    for parts in (1, 4):
+        svc = QueryService(build_database(spec, num_partitions=parts))
+        svc.execute(ALL["Q5"]).rows()
+        got[parts] = svc.stats.exchange_bytes
+        (cp,) = svc.cached_plans()
+        assert cp.exchange_bytes == got[parts]
+    assert got[1] == 0
+    assert got[4] > 0
+
+
+def test_rows_decoded_counts_a_result_once(weather_db_small):
+    svc = QueryService(weather_db_small)
+    rs = svc.execute(ALL["Q4"])
+    snap = svc.stats.snapshot()
+    first = rs.rows()
+    assert rs.rows() == first               # decoding again counts nothing
+    assert svc.stats.diff(snap).rows_decoded == len(first) == 1
+
+
+def test_batched_run_counts_each_request_exchange(weather_db_small):
+    svc = QueryService(weather_db_small)
+    svc.execute(ALL["Q4"])
+    one = svc.stats.exchange_bytes
+    snap = svc.stats.snapshot()
+    rss = svc.execute_batch([ALL["Q4"], _q4("TMIN"), _q4("PRCP")])
+    d = svc.stats.diff(snap)
+    assert d.batches == 1
+    assert d.exchange_bytes == 4 * one       # bucket of 4, one run
+    assert d.fetch_bytes == rss[0].fetch_bytes > 0
+    assert sum(len(rs.rows()) for rs in rss) == 3
+    assert svc.stats.diff(snap).rows_decoded == 3
+
+
+def test_persisted_plan_keeps_exchange_bytes(weather_db_small, tmp_path):
+    svc = QueryService(weather_db_small, persist_dir=str(tmp_path))
+    svc.execute(ALL["Q4"])
+    fresh = QueryService(weather_db_small, persist_dir=str(tmp_path))
+    fresh.execute(ALL["Q4"])
+    assert fresh.stats.compiles == 0 and fresh.stats.persist_hits == 1
+    assert fresh.stats.exchange_bytes == svc.stats.exchange_bytes == 4
