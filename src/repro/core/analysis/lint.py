@@ -79,7 +79,8 @@ from typing import Iterable, Optional
 #: shard_map — the only places the TRACE rules apply.
 TRACED_SCOPES = {
     "core/physical.py": ("ExprEval", "path_match_mask",
-                         "rows_from_mask", "topk_rows", "_gather"),
+                         "rows_from_mask", "_compact_search",
+                         "_compact_blocked", "topk_rows", "_gather"),
     "core/executor.py": ("Executor", "Comm", "hash_join_probe",
                          "_exchange", "_hash_keys"),
 }

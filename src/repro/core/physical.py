@@ -16,7 +16,7 @@ rewrite rules introduced:
   DISTRIBUTE-RESULT   -> per-shard tiles, host concatenation
 
 Cardinality changes (DATASCAN, UNNEST) produce fixed-capacity index
-tiles via ``jnp.nonzero(size=C)`` with an overflow flag — the moral
+tiles via ``rows_from_mask`` with an overflow flag — the moral
 equivalent of Hyracks' frame-size limit, surfaced instead of crashed.
 """
 from __future__ import annotations
@@ -424,29 +424,86 @@ def estimate_topk_cap(db: xdm.Database, tag: str,
     return bound
 
 
+#: lanes per block of the TPU compaction (one vreg row)
+COMPACT_BLOCK = 128
+
+
 def rows_from_mask(mask: jnp.ndarray, cap: int
                    ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """mask [N] -> (idx [cap], valid [cap], overflow). Row order is
     node-table order == document order (rule 4.1.1's free sort).
 
-    Compaction is prefix-count + binary search, not
-    ``jnp.nonzero(size=...)``: the j-th output slot is the first
-    position whose running set-bit count reaches j+1. Bit-identical
-    indices, but scatter-free — XLA CPU lowers the nonzero scatter to
-    a serial while loop that dominated every query's warm latency
-    (the ordered-suite pushdown regression). Its operations run under
-    the ``rows_from_mask`` named scope, so a device profile can tell
-    compaction time from the rest of the plan."""
-    n = mask.shape[0]
-    cap = min(cap, n)
+    ``idx[j]`` is the position of the (j+1)-th set bit, ``NEG`` past
+    the last; ``valid = arange(cap) < total``; ``overflow = total >
+    cap``, with ``cap`` clipped to N. Each backend gets its own
+    lowering, chosen when the program is lowered for it
+    (``lax.platform_dependent``), because the two want opposite
+    things:
+
+    - TPU (``_compact_blocked``): a gather of one scalar per slot costs
+      about 10 ns there, so the per-slot binary search, a loop of
+      log2(N) such gathers, held most of every query's device time.
+      The blocked compaction reads the mask a constant number of times
+      and gathers once.
+    - CPU and the rest (``_compact_search``): prefix count + binary
+      search, scatter-free, where ``jnp.nonzero(size=...)``'s scatter
+      lowers to a serial loop that dominated every query's warm
+      latency (the ordered-suite pushdown regression).
+
+    Both give the same bits. Either runs under the ``rows_from_mask``
+    named scope, so a device profile can tell compaction time from the
+    rest of the plan."""
     with jax.named_scope("rows_from_mask"):
-        pos = jnp.cumsum(mask.astype(I32))
-        total = pos[-1]
-        idx = jnp.searchsorted(pos, jnp.arange(1, cap + 1, dtype=I32))
-        valid = jnp.arange(cap) < total
-        idx = jnp.where(valid, idx, NEG)
-        overflow = total > cap
-        return idx.astype(I32), valid, overflow
+        return jax.lax.platform_dependent(
+            mask, tpu=partial(_compact_blocked, cap=cap),
+            default=partial(_compact_search, cap=cap))
+
+
+def _compact_search(mask: jnp.ndarray, cap: int
+                    ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The j-th slot is the first position whose running set-bit count
+    reaches j+1: a binary search of the prefix count per slot."""
+    cap = min(cap, mask.shape[0])
+    pos = jnp.cumsum(mask.astype(I32))
+    total = pos[-1]
+    idx = jnp.searchsorted(pos, jnp.arange(1, cap + 1, dtype=I32))
+    valid = jnp.arange(cap) < total
+    return jnp.where(valid, idx, NEG).astype(I32), valid, total > cap
+
+
+def _compact_blocked(mask: jnp.ndarray, cap: int
+                     ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Two-level compaction over ``COMPACT_BLOCK``-lane blocks of the
+    mask.
+
+    Inside each block, the lane of its (r+1)-th set bit is the number
+    of lanes whose running count is at most r (a compare and a sum
+    per lane pair, no gather). Slot j then lies in the block whose
+    set bits end past j: scattering each block's width and count at
+    its end (one update per block) and prefix-summing over the slots
+    gives that block's offset and first slot, and one gather of a
+    scalar per slot reads the position from the block table."""
+    n, block = mask.shape[0], COMPACT_BLOCK
+    cap = min(cap, n)
+    nb = -(-n // block)
+    local = jnp.cumsum(jnp.pad(mask, (0, nb * block - n))
+                       .reshape(nb, block).astype(I32), axis=1)
+    count = local[:, -1]
+    end = jnp.cumsum(count)                     # set bits through block b
+    total = end[-1]
+    lane = jnp.arange(block, dtype=I32)
+    first = jnp.arange(nb, dtype=I32)[:, None] * block
+    table = first + jnp.sum((local[:, :, None] <= lane).astype(I32), axis=1)
+    marks = jnp.zeros((cap + 1, 2), I32).at[jnp.minimum(end, cap)].add(
+        jnp.stack([jnp.full((nb,), block, I32), count], axis=1),
+        indices_are_sorted=True)
+    before = jnp.cumsum(marks[:cap], axis=0)    # (offset, first slot)
+    slot = jnp.arange(cap, dtype=I32)
+    valid = slot < total
+    at = jnp.where(valid, before[:, 0] + slot - before[:, 1], 0)
+    idx = jnp.take(table.reshape(-1), at, indices_are_sorted=True,
+                   mode="clip")
+    return jnp.where(valid, idx, NEG).astype(I32), valid, total > cap
 
 
 def topk_rows(sort_keys: list[tuple[jnp.ndarray, bool]],

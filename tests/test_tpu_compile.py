@@ -8,7 +8,8 @@ them, at the widths ``chip_smoke.py`` serves. Mosaic refuses layouts
 the interpreter accepts (unaligned blocks, bool loop carries, 1-D
 refs), and these tests catch that without chip time. One whole grouped
 plan (Q9 through ``Executor.compile``) must lower to a program that
-calls the kernels (``tpu_custom_call``).
+calls the kernels (``tpu_custom_call``), and one scan plan (Q1) to a
+mask compaction with no ``while`` loop.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library, and pytest-xdist workers
@@ -107,3 +108,19 @@ def test_grouped_plan_calls_kernels(one_chip, weather_db, monkeypatch):
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                        sharding=one_chip), ex.tables)
     assert "tpu_custom_call" in cp.fn.lower(tables).compile().as_text()
+
+
+def test_scan_plan_compacts_without_loop(one_chip, weather_db):
+    """Q1's DataScan lowered for the chip takes the blocked compaction:
+    ops run under ``rows_from_mask``, none of them a ``while`` loop.
+    Nothing steers the backend: ``rows_from_mask`` picks its lowering by
+    the platform the program is lowered for."""
+    ex = Executor(weather_db)
+    cp = ex.compile(compile_query(ALL["Q1"]))
+    tables = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=one_chip), ex.tables)
+    lines = cp.fn.lower(tables).compile().as_text().splitlines()
+    scoped = [ln for ln in lines if "rows_from_mask" in ln]
+    assert scoped
+    assert not [ln for ln in scoped if " while(" in ln]
