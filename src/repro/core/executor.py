@@ -430,7 +430,8 @@ class Executor:
                 param_specs: tuple = (),
                 batch: Optional[int] = None,
                 profile: bool = False,
-                aot: bool = False) -> "CompiledPlan":
+                aot: bool = False,
+                lower_only: bool = False) -> "CompiledPlan":
         """Returns a CompiledPlan whose fn maps tables -> raw arrays
         (stacked over partitions); static column schema is captured at
         trace time (strings can't flow through vmap/shard_map).
@@ -466,7 +467,12 @@ class Executor:
         produces exactly the example argument avals), but the
         executable is concrete — which is what the persistent plan
         cache (core/persist.py) serializes. Ignored for donated
-        compilations (one-shot by contract, nothing to persist)."""
+        compilations (one-shot by contract, nothing to persist).
+        ``lower_only=True`` stops an ``aot`` compilation after the
+        trace and lowering: ``fn`` is the ``jax.stages.Lowered``, and
+        the caller makes it the executable with its ``compile()``,
+        which reads no state of the executor and so may run on another
+        thread (``QueryService.warmup`` runs several at once)."""
         cfg = resolve_kernel_policy(plan, config or self.config)
         self.compile_count += 1
         schema: dict[int, tuple] = {}
@@ -509,7 +515,8 @@ class Executor:
                               axis_name=axis)
             out_fn = jit(fn)
             if aot and not donate:
-                out_fn = self._aot_compile(out_fn, param_specs, batch)
+                out_fn = self._aot_lower(out_fn, param_specs, batch,
+                                         lower_only)
             return CompiledPlan(out_fn, schema, plan, cfg, mode,
                                 donated=donate, param_specs=param_specs,
                                 batch=batch, profile_meta=prof_meta,
@@ -552,25 +559,27 @@ class Executor:
                                out_specs=out_spec, check_vma=False)
             out_fn = jit(sm)
             if aot and not donate:
-                out_fn = self._aot_compile(out_fn, param_specs, batch)
+                out_fn = self._aot_lower(out_fn, param_specs, batch,
+                                         lower_only)
             return CompiledPlan(out_fn, schema, plan, cfg, mode,
                                 donated=donate, param_specs=param_specs,
                                 batch=batch, profile_meta=prof_meta,
                                 traced=traced)
         raise ValueError(mode)
 
-    def _aot_compile(self, jitted, param_specs: tuple,
-                     batch: Optional[int]):
+    def _aot_lower(self, jitted, param_specs: tuple,
+                   batch: Optional[int], lower_only: bool):
         """jitted wrapper -> ``jax.stages.Compiled`` via lower+compile
-        with the bound tables and canonical example parameters. One
-        trace either way; AOT just makes the executable a first-class
-        value (serializable by core/persist.py) instead of a cache
-        entry inside jit."""
+        with the bound tables and canonical example parameters (or the
+        ``jax.stages.Lowered`` alone, ``lower_only``). One trace either
+        way; AOT just makes the executable a first-class value
+        (serializable by core/persist.py) instead of a cache entry
+        inside jit."""
+        args = (self.tables,)
         if param_specs:
-            return jitted.lower(self.tables,
-                                example_params(param_specs,
-                                               batch)).compile()
-        return jitted.lower(self.tables).compile()
+            args += (example_params(param_specs, batch),)
+        lowered = jitted.lower(*args)
+        return lowered if lower_only else lowered.compile()
 
     def run(self, plan: A.Op, mode: str = "sim", mesh=None,
             config: Optional[ExecConfig] = None) -> "ResultSet":

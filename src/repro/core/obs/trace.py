@@ -16,7 +16,12 @@ rewrite.<stage>       rewrite    rewrite.engine.optimize, one span per
                                  rule stage (path/parallel/cleanup)
 rewrite-rule          rewrite    instant per rule firing (args: rule)
 compile               service    QueryService.compiled on cache miss
-                                 (trace+jit of one cap/batch variant)
+                                 (trace+jit of one cap/batch variant;
+                                 in ``warmup``, its trace and lowering,
+                                 whose XLA compile then runs on the
+                                 warm-up's thread pool)
+warmup                service    QueryService.warmup (args: variants,
+                                 workers: the pool's compile threads)
 execute               service    QueryService.execute, the whole call:
                                  prepare (memo miss), then the regrowth
                                  ladder

@@ -163,9 +163,11 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import os
 import time
 import types
 from collections import OrderedDict
+from concurrent.futures import ThreadPoolExecutor
 from typing import Optional, Sequence, Union
 
 from repro.core import algebra as A
@@ -517,6 +519,19 @@ class QueryService:
                         "profile_plans")
             return cp
         key = self._key(sig, cfg, batch, False)
+        cp = self._cached(key, plan, cfg, sig, param_specs, batch)
+        if cp is None:
+            cp = self._compile(plan, cfg, sig, param_specs, batch,
+                               profile=False)
+            self._persist_store(cp, sig, batch)
+            self._cache_put(key, cp)
+        return cp
+
+    def _cached(self, key: tuple, plan: A.Op, cfg: ExecConfig, sig: str,
+                param_specs: tuple,
+                batch: Optional[int]) -> Optional[CompiledPlan]:
+        """A serving variant from the in-memory cache (a hit), else from
+        the disk cache (a miss, loaded and cached), else None."""
         cp = self._cache.get(key)
         if cp is not None:
             self._cache.move_to_end(key)
@@ -524,32 +539,39 @@ class QueryService:
             return cp
         self.stats.cache_misses += 1
         cp = self._persist_load(plan, cfg, sig, param_specs, batch)
-        if cp is None:
-            cp = self._compile(plan, cfg, sig, param_specs, batch,
-                               profile=False)
-            self._persist_store(cp, sig, batch)
+        if cp is not None:
+            self._cache_put(key, cp)
+        return cp
+
+    def _cache_put(self, key: tuple, cp: CompiledPlan) -> None:
         self._cache[key] = cp
         before = len(self._cache)
         self._evict(self._cache, self.cache_capacity, "plans")
         self.stats.evictions += before - len(self._cache)
-        return cp
 
     def _compile(self, plan: A.Op, cfg: ExecConfig, sig: str,
                  param_specs: tuple, batch: Optional[int],
-                 profile: bool) -> CompiledPlan:
+                 profile: bool, lower_only: bool = False) -> CompiledPlan:
         """One real trace+compile (the only site). Serving variants
         compile ahead of time (lower+compile to a concrete executable)
         — so ``warmup`` leaves nothing for the first request to
         compile, and the executable can be persisted; profile variants
-        go the lazy route — they are never persisted."""
+        go the lazy route — they are never persisted. ``lower_only``
+        stops after the lowering (``warmup`` compiles the lowered
+        module on its pool and counts it then)."""
         t0 = time.perf_counter()  # lint: allow(DET001) — compile-time metric, cold path only
         with self.tracer.span("compile", cat="service") as span:
             cp = self.executor.compile(
                 plan, mode=self.mode, mesh=self.mesh, config=cfg,
                 param_specs=param_specs, batch=batch, profile=profile,
-                aot=not profile)
+                aot=not profile, lower_only=lower_only)
             span.set(sig=sig_digest(sig), batch=batch,
                      profile=profile)
+        if not lower_only:
+            self._count_compile(sig, time.perf_counter() - t0)  # lint: allow(DET001)
+        return cp
+
+    def _count_compile(self, sig: str, seconds: float) -> None:
         # counted after the compile succeeds, so `stats.compiles` stays
         # the exact mirror of `executor.compile_count` on every path —
         # including regrowth-retry recompiles (scan / join_bucket /
@@ -558,8 +580,7 @@ class QueryService:
         self.stats.compiles += 1
         h = self._history_for(sig)
         h["compiles"] += 1
-        h["compile_s"] += time.perf_counter() - t0  # lint: allow(DET001)
-        return cp
+        h["compile_s"] += seconds
 
     # -- persistent cache plumbing ---------------------------------------
 
@@ -1059,6 +1080,13 @@ class QueryService:
         same known-good/presized configs serving would use, so the
         warmed executables ARE the ones requests hit.
 
+        Variants are traced and lowered in order on the calling
+        thread; their XLA compiles run at the same time on a thread
+        pool of at most one worker per CPU, each submitted as soon as
+        its variant is lowered. The compiled variants are then
+        counted, persisted and cached in order, exactly as one-at-a-
+        time ``compiled`` calls would leave them.
+
         Returns a summary dict: templates prepared, variants warmed,
         compiles actually paid, persist/in-memory hits, and wall
         seconds."""
@@ -1066,31 +1094,39 @@ class QueryService:
         snap = self.stats.snapshot()
         warmed = 0
         seen: set[tuple] = set()
-        with self.tracer.span("warmup", cat="service") as span:
-            for entry in templates:
-                q, width = (entry if isinstance(entry, tuple)
-                            else (entry, None))
-                if width is not None and (not isinstance(width, int)
-                                          or width < 1):
-                    raise InvalidArgumentError(
-                        f"warmup batch width {width!r} must be a "
-                        f"positive int")
-                pq = self.prepare(q)
-                cfg = (self._good_cfg.get(pq.signature)
-                       or self._presized_config(pq.plan))
-                widths: list = [None]
-                if pq.specs:
-                    widths += [w for w in (*batches, width)
-                               if w is not None]
-                for w in widths:
-                    k = (pq.signature, w)
-                    if k in seen:
-                        continue
-                    seen.add(k)
-                    self.compiled(pq.plan, cfg, sig=pq.signature,
-                                  param_specs=pq.specs, batch=w)
-                    warmed += 1
-            span.set(variants=warmed)
+        pending: list[tuple] = []
+        cpus = os.cpu_count() or 1
+        with self.tracer.span("warmup", cat="service") as span, \
+                ThreadPoolExecutor(max_workers=cpus,
+                                   thread_name_prefix="vxq-compile") as pool:
+            try:
+                for entry in templates:
+                    q, width = (entry if isinstance(entry, tuple)
+                                else (entry, None))
+                    if width is not None and (not isinstance(width, int)
+                                              or width < 1):
+                        raise InvalidArgumentError(
+                            f"warmup batch width {width!r} must be a "
+                            f"positive int")
+                    pq = self.prepare(q)
+                    cfg = (self._good_cfg.get(pq.signature)
+                           or self._presized_config(pq.plan))
+                    widths: list = [None]
+                    if pq.specs:
+                        widths += [w for w in (*batches, width)
+                                   if w is not None]
+                    for w in widths:
+                        k = (pq.signature, w)
+                        if k in seen:
+                            continue
+                        seen.add(k)
+                        warmed += 1
+                        item = self._warm_lower(pq, cfg, w, pool)
+                        if item is not None:
+                            pending.append(item)
+            finally:
+                self._warm_finish(pending)
+            span.set(variants=warmed, workers=min(len(pending), cpus))
         d = self.stats.diff(snap)
         return {
             "templates": len(set(s for s, _ in seen)),
@@ -1100,6 +1136,40 @@ class QueryService:
             "cache_hits": d.cache_hits,
             "seconds": time.perf_counter() - t0,  # lint: allow(DET001)
         }
+
+    def _warm_lower(self, pq: PreparedQuery, cfg: ExecConfig,
+                    batch: Optional[int], pool) -> Optional[tuple]:
+        """One warm-up variant as ``compiled`` finds it; one that is
+        neither cached nor persisted is traced and lowered, its compile
+        is submitted to ``pool``, and ``(key, sig, batch, lowering
+        seconds, lowered plan, future)`` is returned."""
+        key = self._key(pq.signature, cfg, batch, False)
+        if self._cached(key, pq.plan, cfg, pq.signature, pq.specs,
+                        batch) is not None:
+            return None
+        t0 = time.perf_counter()  # lint: allow(DET001) — compile-time metric, cold path only
+        cp = self._compile(pq.plan, cfg, pq.signature, pq.specs, batch,
+                           profile=False, lower_only=True)
+        return (key, pq.signature, batch,
+                time.perf_counter() - t0,  # lint: allow(DET001)
+                cp, pool.submit(_timed_compile, cp.fn))
+
+    def _warm_finish(self, pending: list[tuple]) -> None:
+        """Wait for the pool's compiles and keep each variant, in
+        order, as ``compiled`` does; a failed compile is raised after
+        the others are kept."""
+        error = None
+        for key, sig, batch, lower_s, cp, future in pending:
+            try:
+                cp.fn, compile_s = future.result()
+            except Exception as e:
+                error = error or e
+                continue
+            self._count_compile(sig, lower_s + compile_s)
+            self._persist_store(cp, sig, batch)
+            self._cache_put(key, cp)
+        if error is not None:
+            raise error
 
     # -- async multi-tenant frontend ---------------------------------------
 
@@ -1288,3 +1358,10 @@ def _merged_overflow(rss: Sequence[ResultSet]):
         overflow_join_cap=any(rs.overflow_join_cap for rs in rss),
         overflow_group_cap=any(rs.overflow_group_cap for rs in rss),
         overflow_topk_cap=any(rs.overflow_topk_cap for rs in rss))
+
+
+def _timed_compile(lowered) -> tuple:
+    """A lowered module's XLA compile, on a warm-up pool thread: the
+    executable and the seconds it took."""
+    t0 = time.perf_counter()  # lint: allow(DET001) — compile-time metric, cold path only
+    return lowered.compile(), time.perf_counter() - t0  # lint: allow(DET001)

@@ -149,6 +149,25 @@ def test_warmup_cold_compiles_and_stores(weather_db, tmp_path):
     assert svc2.stats.persist_hits == 1
 
 
+def test_concurrent_warmup_persists_like_one_at_a_time(weather_db,
+                                                       tmp_path):
+    """Warm-up's pooled compiles store the same entries as compiles
+    made one at a time by serving."""
+    warm_dir, one_dir = str(tmp_path / "warm"), str(tmp_path / "one")
+    warm = QueryService(weather_db, persist_dir=warm_dir)
+    summary = warm.warmup([ALL[n] for n in TEMPLATES]
+                          + [(ALL[BATCHED], BUCKET)])
+    one = QueryService(weather_db, persist_dir=one_dir)
+    for name in TEMPLATES:
+        one.execute(ALL[name])
+    pq = one.prepare(ALL[BATCHED])
+    one.serve_group(pq, [pq.defaults] * 3, bucket=BUCKET)
+    assert summary["compiles"] == warm.stats.persist_stores == 3
+    assert one.stats.persist_stores == 3
+    assert sorted(os.listdir(warm_dir)) == sorted(os.listdir(one_dir))
+    assert warm.persist_info().entries == one.persist_info().entries == 3
+
+
 def test_warmup_rejects_bad_batch_width(weather_db):
     svc = QueryService(weather_db)
     with pytest.raises(InvalidArgumentError):

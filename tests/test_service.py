@@ -256,3 +256,73 @@ def test_join_cap_regrows_to_exact(weather_db, oracle):
         c for c in caps if c is not None)))
     check(svc2.execute(ALL["Q6"]), oracle, "Q6")
     assert svc2.stats.retries == 0
+
+
+# ---------------------------------------------------------------------------
+# warm-up: XLA compiles on a thread pool
+# ---------------------------------------------------------------------------
+
+WARM = ("Q2", "Q4", "Q9", "Q11")
+
+
+def _compile_threads(monkeypatch, fail_at=None):
+    """Record the thread of every warm-up compile; the ``fail_at``-th
+    one raises instead."""
+    import threading
+
+    from repro.core import service
+    real, seen = service._timed_compile, []
+    lock = threading.Lock()
+
+    def timed(lowered):
+        with lock:
+            seen.append(threading.current_thread().name)
+            n = len(seen)
+        if n == fail_at:
+            raise RuntimeError("compile refused")
+        return real(lowered)
+    monkeypatch.setattr(service, "_timed_compile", timed)
+    return seen
+
+
+def test_concurrent_warmup_matches_one_at_a_time(weather_db, oracle,
+                                                 monkeypatch):
+    import os
+
+    from repro.core.obs.trace import Tracer
+    threads = _compile_threads(monkeypatch)
+    tracer = Tracer()
+    warm = QueryService(weather_db, tracer=tracer)
+    summary = warm.warmup([ALL[n] for n in WARM])
+    one = QueryService(weather_db)
+    rows = {n: one.execute(ALL[n]).rows() for n in WARM}
+
+    assert summary["compiles"] == warm.stats.compiles == len(WARM)
+    assert warm.executor.compile_count == one.stats.compiles == len(WARM)
+    assert list(warm._cache) == list(one._cache)
+    assert len(threads) == len(WARM)
+    assert all(t.startswith("vxq-compile") for t in threads)
+    spans = [s for s in tracer.records if s.name == "compile"]
+    assert len(spans) == len(WARM)
+    (span,) = [s for s in tracer.records if s.name == "warmup"]
+    assert span.args == {"variants": len(WARM),
+                         "workers": min(len(WARM), os.cpu_count())}
+    snap = warm.stats.snapshot()
+    for n in WARM:
+        rs = warm.execute(ALL[n])
+        assert rs.rows() == rows[n]
+        check(rs, oracle, n)
+    assert warm.stats.diff(snap).compiles == 0
+
+
+def test_warmup_keeps_other_plans_when_a_compile_fails(weather_db,
+                                                       monkeypatch):
+    _compile_threads(monkeypatch, fail_at=2)
+    svc = QueryService(weather_db)
+    with pytest.raises(RuntimeError, match="compile refused"):
+        svc.warmup([ALL[n] for n in WARM])
+    assert svc.stats.compiles == svc.cache_size() == len(WARM) - 1
+    snap = svc.stats.snapshot()
+    for n in WARM:
+        svc.execute(ALL[n])
+    assert svc.stats.diff(snap).compiles == 1
