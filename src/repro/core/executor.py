@@ -1263,8 +1263,9 @@ class ResultSet:
     A result keeps the tracer it was produced under (``rows()`` runs
     in its ``decode`` span), the bytes of the device-to-host copy it
     came from (``fetch_bytes``; a batch's results share one copy), and
-    ``on_decode``, called with the row count the first time ``rows()``
-    decodes (the service sets it to count decoded rows)."""
+    ``on_decode``, called with the row count, and the count of those
+    rows that hold a node, the first time ``rows()`` decodes (the
+    service sets it to count decoded rows)."""
 
     def __init__(self, db: xdm.Database, plan: A.Op, raw: dict,
                  schema: dict[int, tuple], profile_meta: dict = None):
@@ -1275,7 +1276,7 @@ class ResultSet:
         self.profile_meta = profile_meta
         self.tracer = obs_trace.current()
         self.fetch_bytes = 0
-        self.on_decode: Optional[Callable[[int], None]] = None
+        self.on_decode: Optional[Callable[[int, int], None]] = None
         self.overflow = bool(np.any(raw["overflow"]))
         # per-stage flags (absent in pre-refactor raw dicts)
         for flag in OVERFLOW_FLAGS.values():    # overflow_scan, ...
@@ -1313,47 +1314,54 @@ class ResultSet:
     def rows(self) -> list[tuple]:
         assert isinstance(self.plan, A.DistributeResult)
         with self.tracer.span("decode", cat="service"):
-            valid = np.asarray(self.raw["valid"])       # [P, T]
-            npart, t = valid.shape
-            out = []
-            for p in range(npart):
-                for r in range(t):
-                    if not valid[p, r]:
-                        continue
-                    row = []
-                    for v in self.plan.vars:
-                        row.append(self._value(v, p, r))
-                    out.append(tuple(row))
+            # the valid slots in row-major order: partition, then slot,
+            # the order ordered results (Q11's top-k) arrive in
+            part, slot = np.nonzero(np.asarray(self.raw["valid"]))
+            cols = [self._column(v, part, slot) for v in self.plan.vars]
+            out = list(zip(*cols)) if cols else [()] * len(part)
         if self.on_decode is not None:
-            self.on_decode(len(out))
+            nodes = any(self.schema[v][0] in ("node", "xnode")
+                        for v in self.plan.vars)
+            self.on_decode(len(out), len(out) if nodes else 0)
             self.on_decode = None           # a result's rows count once
         return out
 
-    def _value(self, v: int, p: int, r: int):
+    def _column(self, v: int, part: np.ndarray, slot: np.ndarray
+                ) -> list:
+        """Variable ``v`` at the slots ``(part, slot)``, as python
+        values: a node as its ``node_fingerprint``, an atom as float,
+        int or bool, a string (``None`` for no sid), and a detached
+        atom as its string if it has one, else its number."""
         kind, table = self.schema[v]
         data = self.raw[f"var{v}"]
+
+        def at(a):
+            return np.asarray(a)[part, slot]
+
         if kind == "node":
-            return node_fingerprint(self.db, table, p,
-                                    int(data[p, r]))
+            return node_fingerprints(self.db, table, part, at(data))
         if kind == "xnode":
-            part, idx = int(data[0][p, r]), int(data[1][p, r])
-            return node_fingerprint(self.db, table, part, idx)
+            return node_fingerprints(self.db, table, at(data[0]),
+                                     at(data[1]))
         if kind == "det":
-            num, sid, date = data
-            s = int(sid[p, r])
-            if s >= 0:
-                return self.db.strings.str(s)
-            return float(num[p, r])
-        if kind == "num":
-            return float(data[p, r])
+            num = at(data[0]).astype(np.float64).astype(object)
+            return self._strings_over(num, at(data[1]))
         if kind == "str":
-            s = int(data[p, r])
-            return self.db.strings.str(s) if s >= 0 else None
+            return self._strings_over(np.full(len(part), None, object),
+                                      at(data))
+        if kind == "num":
+            return at(data).astype(np.float64).tolist()
         if kind == "date":
-            return int(data[p, r])
+            return at(data).astype(np.int64).tolist()
         if kind == "bool":
-            return bool(data[p, r])
+            return at(data).astype(bool).tolist()
         raise TypeError(kind)
+
+    def _strings_over(self, col: np.ndarray, sid: np.ndarray) -> list:
+        """``col`` with the string of each sid >= 0 in its place."""
+        has = sid >= 0
+        col[has] = self.db.strings.array()[sid[has]]
+        return col.tolist()
 
     def scalar(self) -> float:
         rows = self.rows()
@@ -1388,3 +1396,51 @@ def node_fingerprint(db: xdm.Database, collection: str, part: int,
             v = float(t.text_num[j])
             out.append(str(int(v)) if v.is_integer() else f"{v:.1f}")
     return "|".join(out)
+
+
+def node_fingerprints(db: xdm.Database, collection: str, part: np.ndarray,
+                      idx: np.ndarray) -> list[str]:
+    """``node_fingerprint`` of each node ``(part[i], idx[i])``, by
+    column: one pass per source partition over the descendant rows of
+    all its nodes (each node's subtree is a contiguous run of rows)."""
+    tables = db.collection(collection).partitions
+    out = np.empty(len(idx), object)
+    for q in np.unique(part).tolist():
+        at = np.nonzero(part == q)[0]
+        out[at] = _subtree_texts(db.strings, tables[q], idx[at])
+    return out.tolist()
+
+
+def _subtree_texts(strings: xdm.StringDict, t: xdm.NodeTable,
+                   idx: np.ndarray) -> np.ndarray:
+    out = np.full(len(idx), "<invalid>", object)
+    ok = np.nonzero((idx >= 0) & (idx < t.num_nodes))[0]
+    start = idx[ok].astype(np.int64)
+    length = t.subtree_size[start]
+    # the subtree rows of every node, node after node
+    first = np.cumsum(length) - length
+    rows = np.arange(int(length.sum())) - np.repeat(first - start, length)
+    sid, num = t.text_sid[rows], t.text_num[rows]
+    keep = (sid >= 0) | ~np.isnan(num)         # rows that give a token
+    node = np.repeat(np.arange(len(start)), length)[keep]
+    sid, num = sid[keep], num[keep]
+    tok = np.empty(len(sid), object)
+    is_str = sid >= 0
+    tok[is_str] = strings.array()[sid[is_str]]
+    # a number's text is formatted once, however many rows hold it
+    vals, which = np.unique(num[~is_str], return_inverse=True)
+    texts = np.empty(len(vals), object)
+    texts[:] = [str(int(v)) if v.is_integer() else f"{v:.1f}"
+                for v in vals.tolist()]
+    tok[~is_str] = texts[which]
+    # join per node, the nodes with c tokens at a time: their tokens,
+    # gathered flat, are consumed c by c
+    count = np.bincount(node, minlength=len(start))
+    begin = np.cumsum(count) - count
+    col = np.full(len(start), "", object)
+    for c in np.unique(count[count > 0]).tolist():
+        at = np.nonzero(count == c)[0]
+        flat = iter(tok[(begin[at, None] + np.arange(c)).ravel()].tolist())
+        col[at] = list(map("|".join, zip(*[flat] * c)))
+    out[ok] = col
+    return out

@@ -60,11 +60,12 @@ REGISTERED_STATS = {
     # — "evictions" above stays the level-1 total for compatibility
     "evictions_by_cache": ("cache_evictions_total", "cache"),
     # the served path's stages: front-end runs (prepare-memo misses),
-    # device-to-host bytes, decoded rows, and the logical bytes the
-    # plans' collectives moved
+    # device-to-host bytes, decoded rows (and those holding a node),
+    # and the logical bytes the plans' collectives moved
     "prepares": "prepares_total",
     "fetch_bytes": "fetch_bytes_total",
     "rows_decoded": "rows_decoded_total",
+    "node_rows_decoded": "node_rows_decoded_total",
     "exchange_bytes": "exchange_bytes_total",
     # RuntimeStats (core/serving/scheduler.py)
     "submitted": "submitted_total",

@@ -234,10 +234,13 @@ class ServiceStats:
     # memo and ran the front end (parse, rewrite, lift, verify); bytes
     # of the output tiles copied device-to-host; rows ResultSet.rows()
     # produced; and the logical bytes the runs' collectives moved
-    # (CompiledPlan.exchange_bytes, counted once per device run)
+    # (CompiledPlan.exchange_bytes, counted once per device run).
+    # node_rows_decoded counts the decoded rows that hold a node, whose
+    # columns are fingerprinted from the node tables' subtrees
     prepares: int = 0
     fetch_bytes: int = 0
     rows_decoded: int = 0
+    node_rows_decoded: int = 0
     exchange_bytes: int = 0
 
     @property
@@ -723,8 +726,9 @@ class QueryService:
         for rs in rss:
             rs.on_decode = self._note_decoded
 
-    def _note_decoded(self, n: int) -> None:
+    def _note_decoded(self, n: int, nodes: int) -> None:
         self.stats.rows_decoded += n
+        self.stats.node_rows_decoded += nodes
 
     def _note_binding(self, sig: str, values: tuple) -> None:
         key = (sig, values)

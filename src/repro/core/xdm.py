@@ -31,6 +31,7 @@ carries per-sid derived arrays (e.g. ``ucase_sid`` for upper-case()).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import re
 from typing import Any, Iterable, Optional
@@ -54,6 +55,7 @@ class StringDict:
     def __init__(self) -> None:
         self._to_id: dict[str, int] = {}
         self._strings: list[str] = []
+        self._array: Optional[np.ndarray] = None
 
     def id(self, s: str) -> int:
         i = self._to_id.get(s)
@@ -72,6 +74,16 @@ class StringDict:
 
     def __len__(self) -> int:
         return len(self._strings)
+
+    def array(self) -> np.ndarray:
+        """The strings as an object array indexed by sid, for decoding
+        a column of sids with one gather. Ids are append-only, so the
+        array is rebuilt only when the dictionary has grown."""
+        if self._array is None or len(self._array) != len(self._strings):
+            arr = np.empty(len(self._strings), object)
+            arr[:] = self._strings
+            self._array = arr
+        return self._array
 
     def derived_arrays(self) -> dict[str, np.ndarray]:
         """Per-sid device side tables: uppercase map, numeric, date."""
@@ -124,6 +136,25 @@ class NodeTable:
     @property
     def num_nodes(self) -> int:
         return int(self.kind.shape[0])
+
+    @functools.cached_property
+    def subtree_size(self) -> np.ndarray:
+        """[N] rows in each node's subtree, the node included. Rows are
+        in document order, so node i's descendants are the rows
+        ``(i, i + subtree_size[i])``. Computed once from the parent
+        pointers: depth level by level, then sizes from the deepest
+        level up."""
+        parent = self.parent
+        depth = np.zeros(parent.shape, np.int32)
+        up = parent
+        while np.any(live := up >= 0):
+            depth += live
+            up = np.where(live, parent[np.maximum(up, 0)], -1)
+        size = np.ones(parent.shape, np.int32)
+        for d in range(int(depth.max(initial=0)), 0, -1):
+            at = np.nonzero(depth == d)[0]
+            np.add.at(size, parent[at], size[at])
+        return size
 
     def tag_counts(self) -> dict[int, int]:
         """Node count per element/attribute name id — the per-tag
