@@ -4,11 +4,11 @@
 Wall-clock caveat (single-core container): this box exposes ONE core,
 so partition-parallel *wall* speedup cannot manifest; what the
 speed-up/scale-up benches measure instead is per-partition work and
-total throughput — the quantity that determines cluster scaling, with
-the dry-run proving the partitioned lowering. The paper's qualitative
-claims (rewrites ~3x vs Saxon-style evaluation, ~2.5x vs MapReduce-
-style staging) reproduce directly in wall time because they are
-single-node effects.
+total throughput — the quantity that determines cluster scaling (the
+chip benchmark's 4-chip cell measures it on a mesh). The paper's
+qualitative claims (rewrites ~3x vs Saxon-style evaluation, ~2.5x vs
+MapReduce-style staging) reproduce directly in wall time because they
+are single-node effects.
 """
 from __future__ import annotations
 

@@ -90,7 +90,7 @@ def fig56_speedup(queries=("Q2", "Q4"), parts=(1, 2, 4, 8)) -> None:
             cp = _guarded_compile(ex, plan)
             t = timeit(lambda: cp.fn(ex.tables))
             row("fig56_speedup", f"{name}/p{p}", "wall_s", t,
-                "1-core box: wall ~flat; see dryrun for scaling")
+                "1-core box: wall ~flat; see the 4-chip cell for scaling")
 
 
 def fig89_scaleup(queries=("Q2", "Q4"), parts=(1, 2, 4, 8)) -> None:

@@ -4,9 +4,7 @@
 ``PYTHONPATH=src python -m benchmarks.run --suite groupby``  (the
 group-by serving workload, reproducible with one command)
 
-CSV rows: table,name,metric,value,derived. The roofline section reads
-the dry-run artifacts (run ``python -m repro.launch.dryrun --all``
-first for the full table; missing artifacts are reported, not fatal).
+CSV rows: table,name,metric,value,derived.
 """
 from __future__ import annotations
 
@@ -41,7 +39,7 @@ def main() -> None:
     args = ap.parse_args()
     compile_cache.enable()
 
-    from benchmarks import lm_benchmarks, q_benchmarks, serving_benchmarks
+    from benchmarks import q_benchmarks, serving_benchmarks
 
     sections = {
         "fig5_vs_saxon": lambda: q_benchmarks.fig5_vs_saxon(
@@ -100,10 +98,6 @@ def main() -> None:
                 variants=8 if args.quick else 64,
                 smoke=args.quick),
         "ingest": q_benchmarks.ingest,
-        "lm_train": lm_benchmarks.train_step_smoke,
-        "lm_attention": lm_benchmarks.attention_impls,
-        "lm_serve": lm_benchmarks.decode_throughput,
-        "roofline": _roofline,
     }
     if args.suite:
         chosen = SUITES[args.suite]
@@ -123,17 +117,6 @@ def main() -> None:
             traceback.print_exc()
     if failures:
         raise SystemExit(f"failed sections: {failures}")
-
-
-def _roofline() -> None:
-    import os
-    from benchmarks import roofline
-    if not os.path.isdir("experiments/dryrun") or not os.listdir(
-            "experiments/dryrun"):
-        print("# roofline: no dry-run artifacts; run "
-              "`python -m repro.launch.dryrun --all` first")
-        return
-    roofline.main()
 
 
 if __name__ == "__main__":

@@ -9,7 +9,7 @@
 # tests).
 #
 #   scripts/ci.sh                 default loop (slow-marked smokes skipped)
-#   FULL=1 scripts/ci.sh          include slow-marked arch smoke tests
+#   FULL=1 scripts/ci.sh          include the slow-marked full grids
 #   scripts/ci.sh --differential  also run the differential-harness fast
 #                                 slice as its own stage (prepared/batch/
 #                                 regrowth/scheduled bit-parity across

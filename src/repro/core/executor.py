@@ -7,7 +7,7 @@ function run under
   * ``jax.vmap(..., axis_name="data")``  — cluster simulation on one
     device (tests/benchmarks; collectives become batched reductions)
   * ``shard_map(..., mesh, axis "data")`` — real SPMD over the mesh
-    (multi-device runs and the 512-way dry-run)
+    (multi-device runs: the 4-chip cell, tests on forced host devices)
 
 with identical ``lax`` collectives inside (psum for two-step
 aggregation, all_gather for the hybrid-hash build broadcast, all_to_all

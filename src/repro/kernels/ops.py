@@ -1,9 +1,8 @@
-"""Jitted public wrappers around the Pallas kernels.
+"""Public wrappers around the Pallas kernels: the executor's entry points.
 
-On CPU (this container) the attention/join kernels execute with
-``interpret=True`` — the kernel body runs as traced JAX ops,
-validating the exact code that compiles for TPU. On a real TPU
-backend interpret switches off.
+On CPU the join kernel executes with ``interpret=True`` — the kernel
+body runs as traced JAX ops, validating the exact code that compiles
+for TPU. On a real TPU backend interpret switches off.
 
 The *segment engine* entry points (``segmented_aggregate``,
 ``segment_topk``) are three-way instead: on TPU they run the Pallas
@@ -19,13 +18,10 @@ twin everywhere — the escape hatch documented in README.
 from __future__ import annotations
 
 import os
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import decode_attention as _dec
-from repro.kernels import flash_attention as _fa
 from repro.kernels import hash_join as _hj
 from repro.kernels import ref as _ref
 from repro.kernels import seg_aggregate as _seg
@@ -59,43 +55,6 @@ def _block_divisor(n: int, target: int = 512) -> int:
 # vmap partition simulation — the context every query runs in). The
 # serving path's statistics-presized group caps sit well below it.
 SEG_DENSE_NSEG_MAX = 32
-
-
-@partial(jax.jit, static_argnames=("causal", "window", "logit_softcap",
-                                   "scale", "block_q", "block_k"))
-def flash_attention(q, k, v, *, causal=True, window=None,
-                    logit_softcap=None, scale=None,
-                    block_q=128, block_k=128):
-    """q: (B, Sq, Hq, D); k, v: (B, Sk, Hkv, D) -> (B, Sq, Hq, D)."""
-    b, sq, hq, d = q.shape
-    _, sk, hkv, _ = k.shape
-    g = hq // hkv
-    qb = jnp.moveaxis(q, 2, 1).reshape(b * hq, sq, d)
-    kb = jnp.moveaxis(k, 2, 1).reshape(b * hkv, sk, d)
-    vb = jnp.moveaxis(v, 2, 1).reshape(b * hkv, sk, d)
-    out = _fa.flash_attention_bhsd(
-        qb, kb, vb, g=g, causal=causal, window=window,
-        softcap=logit_softcap, scale=scale, block_q=block_q,
-        block_k=block_k, interpret=_interpret())
-    return jnp.moveaxis(out.reshape(b, hq, sq, d), 1, 2)
-
-
-@partial(jax.jit, static_argnames=("window", "logit_softcap", "scale",
-                                   "block_k"))
-def decode_attention(q, k_cache, v_cache, kv_len, *, window=None,
-                     logit_softcap=None, scale=None, block_k=512):
-    """q: (B, 1, Hq, D); caches: (B, Smax, Hkv, D); kv_len: (B,)."""
-    b, _, hq, d = q.shape
-    _, sk, hkv, _ = k_cache.shape
-    g = hq // hkv
-    qb = q.reshape(b, hq, d).reshape(b, hkv, g, d).reshape(b * hkv, g, d)
-    kb = jnp.moveaxis(k_cache, 2, 1).reshape(b * hkv, sk, d)
-    vb = jnp.moveaxis(v_cache, 2, 1).reshape(b * hkv, sk, d)
-    kvb = jnp.repeat(kv_len, hkv)
-    out = _dec.decode_attention_bhgd(
-        qb, kb, vb, kvb, window=window, softcap=logit_softcap,
-        scale=scale, block_k=block_k, interpret=_interpret())
-    return out.reshape(b, 1, hq, d)
 
 
 def hash_join_probe(build_keys, build_valid, probe_keys, probe_valid,

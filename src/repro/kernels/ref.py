@@ -4,53 +4,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-NEG_INF = -2.0e38
-
-
-def flash_attention(q, k, v, *, g, causal=True, window=None,
-                    softcap=None, scale=None):
-    """q: (BH, Sq, D); k, v: (BHkv, Sk, D). Dense reference."""
-    bh, sq, d = q.shape
-    bhkv, sk, _ = k.shape
-    scale = scale if scale is not None else d ** -0.5
-    kq = jnp.repeat(k, g, axis=0)
-    vq = jnp.repeat(v, g, axis=0)
-    s = jnp.einsum("hqd,hkd->hqk", q.astype(jnp.float32) * scale,
-                   kq.astype(jnp.float32))
-    if softcap is not None:
-        s = jnp.tanh(s / softcap) * softcap
-    qp = jnp.arange(sq)[:, None]
-    kp = jnp.arange(sk)[None, :]
-    ok = jnp.ones((sq, sk), bool)
-    if causal:
-        ok &= kp <= qp
-    if window is not None:
-        ok &= kp > (qp - window)
-    s = jnp.where(ok, s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("hqk,hkd->hqd", p,
-                      vq.astype(jnp.float32)).astype(q.dtype)
-
-
-def decode_attention(q, k, v, kv_len, *, window=None, softcap=None,
-                     scale=None):
-    """q: (BH, G, D); k, v: (BH, Sk, D); kv_len: (BH,)."""
-    bh, g, d = q.shape
-    _, sk, _ = k.shape
-    scale = scale if scale is not None else d ** -0.5
-    s = jnp.einsum("hgd,hkd->hgk", q.astype(jnp.float32) * scale,
-                   k.astype(jnp.float32))
-    if softcap is not None:
-        s = jnp.tanh(s / softcap) * softcap
-    kp = jnp.arange(sk)[None, None, :]
-    ok = kp < kv_len[:, None, None]
-    if window is not None:
-        ok &= kp > (kv_len[:, None, None] - 1 - window)
-    s = jnp.where(ok, s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("hgk,hkd->hgd", p,
-                      v.astype(jnp.float32)).astype(q.dtype)
-
 
 def block_join_probe(build_keys, build_valid, probe_keys, probe_valid):
     """First-match (build order) equi-join. O(NP*NB) dense compare."""

@@ -14,8 +14,6 @@ importing jax.
 from __future__ import annotations
 
 KERNEL_REFS: dict[str, str] = {
-    "flash_attention.flash_attention_bhsd": "flash_attention",
-    "decode_attention.decode_attention_bhgd": "decode_attention",
     "hash_join.block_join_probe": "block_join_probe",
     "seg_aggregate.segmented_aggregate": "segmented_aggregate",
     "seg_topk.segment_topk": "segment_topk",

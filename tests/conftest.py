@@ -1,6 +1,6 @@
-"""Shared fixtures. NOTE: no XLA_FLAGS here — smoke tests and benches
-must see 1 device; only launch/dryrun.py forces 512 host devices, and
-tests/test_distributed.py spawns subprocesses with their own flags."""
+"""Shared fixtures. NOTE: no XLA_FLAGS here — tests and benches must
+see 1 device; tests/test_distributed.py spawns subprocesses that force
+their own host device counts."""
 import os
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")

@@ -270,28 +270,38 @@ def test_registry_completeness():
     assert capflow.registry_coverage() == executor.OVERFLOW_FLAGS
 
 
-def test_capflow_invariant_under_kernel_policy(weather_db_small):
+@pytest.fixture(scope="module")
+def kernel_and_plain(weather_db_small):
+    """Services that differ only in the kernel knobs."""
+    return tuple(
+        service.QueryService(
+            weather_db_small,
+            executor.ExecConfig(use_pallas_segments=on,
+                                use_pallas_join=on))
+        for on in (True, False))
+
+
+@pytest.mark.parametrize("name", list(queries.ALL))
+def test_capflow_invariant_under_kernel_policy(kernel_and_plain,
+                                              weather_db_small, name):
     """The kernel knobs pick an implementation (Pallas kernel vs jnp
-    twin), never capacity semantics: for every query, the kernel-path
-    and jnp-path compilations derive the identical capacity-site set
-    — same caps, same flags, same operator paths, same static bounds.
-    The fused kernels read the same resolved caps and raise the same
+    twin), never capacity semantics: the kernel-path and jnp-path
+    compilations derive the identical capacity-site set — same caps,
+    same flags, same operator paths, same static bounds. The fused
+    kernels read the same resolved caps and raise the same
     OVERFLOW_FLAGS entries, so regrowth ladders are path-independent."""
-    kern = service.QueryService(
-        weather_db_small,
-        executor.ExecConfig(use_pallas_segments=True,
-                            use_pallas_join=True))
-    plain = service.QueryService(
-        weather_db_small,
-        executor.ExecConfig(use_pallas_segments=False,
-                            use_pallas_join=False))
-    for name in queries.ALL:
-        fk = capflow.analyze(kern.prepare(queries.ALL[name]).plan,
-                             db=weather_db_small)
-        fj = capflow.analyze(plain.prepare(queries.ALL[name]).plan,
-                             db=weather_db_small)
-        assert fk.sites == fj.sites, name
-        assert fk.caps and fk.flags, name
+    kern, plain = kernel_and_plain
+    fk = capflow.analyze(kern.prepare(queries.ALL[name]).plan,
+                         db=weather_db_small)
+    fj = capflow.analyze(plain.prepare(queries.ALL[name]).plan,
+                         db=weather_db_small)
+    assert fk.sites == fj.sites, name
+    assert fk.caps and fk.flags, name
+
+
+def test_overflow_flags_are_config_fields():
+    """Every OVERFLOW_FLAGS cap is an ExecConfig field, and the cache
+    key covers every field."""
     fields = {f.name for f in dataclasses.fields(executor.ExecConfig)}
     for cap in executor.OVERFLOW_FLAGS:
         assert cap in fields
@@ -384,7 +394,7 @@ def test_lint_wall_clock_in_core():
     assert _codes(lint.lint_source(
         src, "repro/core/serving/x.py")) == ["DET001"]
     # and not outside core/
-    assert lint.lint_source(src, "repro/launch/bench.py") == []
+    assert lint.lint_source(src, "repro/data/weather.py") == []
 
 
 def test_lint_unseeded_rng_in_core():

@@ -2,10 +2,14 @@
 beyond-paper feature on the keyed two-step aggregation path)."""
 import numpy as np
 import pytest
+from conftest import check_result
 
-from repro.core import ExecConfig, Executor, compile_query
+from repro.core import ExecConfig, Executor, QueryService, compile_query
 from repro.core.algebra import GroupBy, walk
 from repro.core.baselines import SaxonLike
+from repro.core.executor import SEG_COMPARE_CAP_MAX
+from repro.core.queries import ALL, GROUPED
+from repro.data.weather import WeatherSpec, build_database
 
 GB_QUERY = '''
 for $r in collection("/sensors")/dataCollection/data
@@ -164,7 +168,6 @@ def test_groupby_minmax_skip_nonnumeric_values():
 
 
 def test_groupby_partition_invariance():
-    from repro.data.weather import WeatherSpec, build_database
     spec = WeatherSpec(num_stations=6, years=(2000, 2001),
                        days_per_year=3)
     results = []
@@ -174,3 +177,38 @@ def test_groupby_partition_invariance():
         results.append(sorted((r[0], round(r[1], 3), round(r[2], 2))
                               for r in rows))
     assert results[0] == results[1]
+
+
+# Two centuries of dates at eight readings a year intern more strings
+# than the largest cap below, so every cap takes the capped segment
+# space (cap < dictionary) instead of one slot per string.
+WIDE_DICT_SPEC = WeatherSpec(num_stations=6, years=tuple(range(1740, 2010)),
+                             days_per_year=8, datatypes=("TMAX", "PRCP"))
+
+
+@pytest.fixture(scope="module")
+def wide_dict():
+    """(db, oracle): a database whose string dictionary exceeds 2048,
+    and the SaxonLike rows of each grouped template on it."""
+    db = build_database(WIDE_DICT_SPEC, num_partitions=4)
+    sx = SaxonLike(db)
+    return db, {n: sorted(sx.run_rows(ALL[n])) for n in GROUPED}
+
+
+@pytest.mark.parametrize("cap", [SEG_COMPARE_CAP_MAX,
+                                 SEG_COMPARE_CAP_MAX + 1, 512, 2048])
+@pytest.mark.parametrize("name", GROUPED)
+def test_segment_mapping_compare_and_search(wide_dict, name, cap):
+    """The fused group-by maps rows to segments by dense compare up to
+    SEG_COMPARE_CAP_MAX and by searchsorted above it; both branches,
+    at the caps the benchmark cells' plans use (512 slots on one chip,
+    2048 on four), serve exact rows at the requested cap."""
+    db, oracle = wide_dict
+    assert cap < len(db.strings)
+    svc = QueryService(db, ExecConfig(group_cap=cap))
+    rs = svc.execute(ALL[name])
+    assert not rs.overflow
+    assert svc.stats.retries == 0
+    assert [(c.group_cap, c.use_pallas_segments)
+            for c in svc.cached_configs()] == [(cap, True)]
+    check_result(rs, oracle, name)

@@ -2,6 +2,7 @@
 import pytest
 
 from repro.core import xqparser as xq
+from repro.core.queries import ALL
 
 
 def test_flwor_multi_for_where_return():
@@ -70,8 +71,7 @@ def test_syntax_errors():
             xq.parse(bad)
 
 
-def test_paper_queries_all_parse():
-    from repro.core.queries import ALL
-    for name, q in ALL.items():
-        ast = xq.parse(q)
-        assert ast is not None, name
+@pytest.mark.parametrize("name", list(ALL))
+def test_paper_queries_all_parse(name):
+    ast = xq.parse(ALL[name])
+    assert ast is not None, name

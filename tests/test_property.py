@@ -134,18 +134,3 @@ def test_join_probe_property(data):
             assert bk[pos[i]] == pk[i]
         elif pv[i]:
             assert pk[i] not in valid_build
-
-
-def test_adamw_tree_roundtrip():
-    """Optimizer update preserves pytree structure incl. tuples."""
-    import jax
-    from repro.optim import adamw_init, adamw_update
-    params = {"blocks": ({"w": jnp.ones((4, 4))},
-                         {"w": jnp.ones((4, 4)) * 2}),
-              "embed": jnp.ones((8, 4))}
-    opt = adamw_init(params)
-    grads = jax.tree.map(jnp.ones_like, params)
-    p2, o2, m = adamw_update(grads, opt, params, lr=1e-2)
-    assert jax.tree_util.tree_structure(p2) == \
-        jax.tree_util.tree_structure(params)
-    assert int(o2["step"]) == 1

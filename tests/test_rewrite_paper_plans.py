@@ -5,6 +5,7 @@ import pytest
 from repro.core.algebra import (Aggregate, Assign, Call, DataScan,
                                 DistributeResult, Join, Select, Subplan,
                                 Unnest, pretty, signature, walk)
+from repro.core.queries import ALL
 from repro.core.rewrite import optimize, run_rules
 from repro.core.rewrite import path_rules as pr
 from repro.core.rewrite import parallel_rules as rr
@@ -149,10 +150,8 @@ def test_paper_trace_text_books():
     assert 'sort-distinct-nodes-asc-or-atomics' in txt
 
 
-def test_q_plans_all_compile(weather_db):
-    from repro.core.queries import ALL
-    for name, q in ALL.items():
-        plan = optimize(translate(q))
-        kinds = sig(plan)
-        assert kinds[0] == "DistributeResult"
-        assert any(k.startswith("DataScan:") for k in kinds), name
+@pytest.mark.parametrize("name", list(ALL))
+def test_q_plans_all_compile(name):
+    kinds = sig(optimize(translate(ALL[name])))
+    assert kinds[0] == "DistributeResult"
+    assert any(k.startswith("DataScan:") for k in kinds), name

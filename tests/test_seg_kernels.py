@@ -160,6 +160,29 @@ def test_ops_count_only_and_topk_dispatch():
         np.asarray(ref.segment_topk(keys, 8)))
 
 
+AGGS = ("count", "sum", "min", "max")   # ops.segmented_aggregate's outputs
+
+
+@pytest.mark.parametrize("agg", AGGS)
+@pytest.mark.parametrize("nseg", [ops.SEG_DENSE_NSEG_MAX - 1,
+                                  ops.SEG_DENSE_NSEG_MAX,
+                                  ops.SEG_DENSE_NSEG_MAX + 1, 512, 2048])
+def test_ops_segmented_aggregate_across_dense_cap(nseg, agg):
+    """The group-by entry point on both sides of its dense/scatter
+    threshold, and at the segment spaces the benchmark cells' plans
+    use, equals the host per-segment reduction — empty segments
+    included (every fourth segment receives no rows)."""
+    rng = np.random.default_rng([nseg, AGGS.index(agg)])
+    vals, ok, segs, valid = _agg_case(1024, nseg, 2, rng)
+    segs = jnp.where(segs % 4 == 3, -1, segs)
+    out = ops.segmented_aggregate(vals, ok, segs, valid, nseg)
+    want = _host_agg(vals, ok, segs, valid, nseg)
+    i = AGGS.index(agg)
+    assert out[i].shape == want[i].shape
+    assert (want[0] == 0).any()
+    np.testing.assert_array_equal(np.asarray(out[i]), want[i])
+
+
 # ---------------------------------------------------------------------------
 # 3. seeded property suites (host oracles)
 # ---------------------------------------------------------------------------

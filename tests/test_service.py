@@ -47,18 +47,18 @@ def test_tiny_caps_regrow_to_exact(weather_db, oracle, name):
     assert delta.cache_hits >= 1
 
 
-def test_presized_caps_avoid_retries(weather_db, oracle):
-    """Build-time statistics pre-size first-shot caps: all eight paper
-    queries run exactly with zero overflow retries, and none of them
-    needed the padded-table fallback capacity."""
+@pytest.mark.parametrize("name", list(ALL))
+def test_presized_caps_avoid_retries(weather_db, oracle, name):
+    """Build-time statistics pre-size first-shot caps: each paper
+    query runs exactly with zero overflow retries, and none needed the
+    padded-table fallback capacity."""
     svc = QueryService(weather_db)
-    for name in ALL:
-        check(svc.execute(ALL[name]), oracle, name)
+    check(svc.execute(ALL[name]), oracle, name)
     assert svc.stats.retries == 0
-    assert svc.stats.executions == len(ALL)
+    assert svc.stats.executions == 1
     tight = [c.scan_cap for c in svc.cached_configs()]
-    assert all(cap is not None and cap < svc._scan_ceiling
-               for cap in tight), tight
+    assert tight and all(cap is not None and cap < svc._scan_ceiling
+                         for cap in tight), tight
 
 
 def test_repeated_query_hits_cache(weather_db, oracle):
